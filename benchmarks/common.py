@@ -1,8 +1,9 @@
 """Shared benchmark utilities: timing + CSV emission.
 
-CPU-container caveat (DESIGN.md §9): wall-clock numbers here are CPU
-measurements used as *relative* signals between variants; the TPU
-performance story is the dry-run roofline (benchmarks/roofline.py).
+Wall-clock numbers are taken on whatever backend JAX runs on.  Every
+`BENCH_*.json` in the repository so far was written on the CPU, so its
+timings say nothing about the TPU; only a run on the chip (see
+`chip_smoke.py`) gives device times.
 """
 from __future__ import annotations
 

@@ -1,7 +1,4 @@
 """repro: cuSZ (PACT'20) reproduced as a TPU-native JAX compression
 substrate inside a multi-pod LM training/serving framework."""
-from repro import _compat as _compat
-
-_compat.install()
 
 __version__ = "1.0.0"

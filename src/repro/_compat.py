@@ -1,33 +1,13 @@
-"""Forward-compatibility shims for older jax releases.
+"""``warn_once``, the process-wide deprecation helper.
 
-The codebase (and its tests) target the jax>=0.5 mesh surface:
-
-  * ``jax.sharding.AxisType`` + ``jax.make_mesh(..., axis_types=...)``
-  * ``jax.sharding.AbstractMesh(axis_sizes, axis_names)`` (two positional
-    arguments instead of 0.4.x's single ``shape_tuple``)
-
-On a 0.4.x install (this container ships 0.4.37) those names/signatures
-do not exist yet, so every mesh constructor would die with
-``AttributeError``/``TypeError`` before any model code runs.  Importing
-``repro`` applies the patches below exactly once; on a new-enough jax
-this module is a no-op.  All axes are semantically ``Auto`` (the SPMD
-partitioner decides), which is also 0.4.x's only behavior, so dropping
-``axis_types`` loses nothing.
-
-This module also hosts ``warn_once``, the process-wide deprecation
-helper: Python's own per-location warning dedup resets whenever the
-filter stack changes (pytest installs ``always``), so shims that should
-warn exactly once per process keep their own seen-set here.
+Python's own per-location warning dedup resets whenever the filter stack
+changes (pytest installs ``always``), so shims that should warn exactly
+once per process keep their own seen-set here.
 """
 from __future__ import annotations
 
-import enum
-import functools
 import warnings
 from typing import Set
-
-import jax
-from jax import sharding as _sharding
 
 _WARNED: Set[str] = set()
 
@@ -44,60 +24,3 @@ def warn_once(key: str, message: str, *, category=DeprecationWarning,
         return
     _WARNED.add(key)
     warnings.warn(message, category, stacklevel=stacklevel)
-
-
-def _patch_axis_type() -> None:
-    if hasattr(_sharding, "AxisType"):
-        return
-
-    class AxisType(enum.Enum):
-        Auto = "auto"
-        Explicit = "explicit"
-        Manual = "manual"
-
-    _sharding.AxisType = AxisType
-
-
-def _patch_make_mesh() -> None:
-    try:
-        import inspect
-        if "axis_types" in inspect.signature(jax.make_mesh).parameters:
-            return
-    except (TypeError, ValueError):  # pragma: no cover - exotic builds
-        return
-    real = jax.make_mesh
-
-    @functools.wraps(real)
-    def make_mesh(axis_shapes, axis_names, *, devices=None, axis_types=None):
-        del axis_types            # 0.4.x: every axis is implicitly Auto
-        return real(axis_shapes, axis_names, devices=devices)
-
-    jax.make_mesh = make_mesh
-
-
-def _patch_abstract_mesh() -> None:
-    real = _sharding.AbstractMesh
-    try:                          # new-style signature already supported?
-        real((1,), ("x",))
-        return
-    except TypeError:
-        pass
-    except Exception:             # pragma: no cover - unexpected semantics
-        return
-
-    class AbstractMesh(real):     # type: ignore[misc,valid-type]
-        """0.4.x AbstractMesh accepting the >=0.5 (sizes, names) form."""
-
-        def __init__(self, axis_sizes, axis_names=None, *, axis_types=None):
-            del axis_types
-            if axis_names is not None:
-                axis_sizes = tuple(zip(axis_names, axis_sizes))
-            super().__init__(tuple(axis_sizes))
-
-    _sharding.AbstractMesh = AbstractMesh
-
-
-def install() -> None:
-    _patch_axis_type()
-    _patch_make_mesh()
-    _patch_abstract_mesh()

@@ -38,8 +38,8 @@ def _hist_kernel(nbins, tile, codes_ref, out_ref):
     out_ref[...] += part.astype(jnp.int32)
 
 
-def histogram_pallas(codes: jax.Array, nbins: int, tile: int = 2048,
-                     interpret: bool = True) -> jax.Array:
+def histogram_pallas(codes: jax.Array, nbins: int, tile: int = 2048, *,
+                     interpret: bool) -> jax.Array:
     flat = codes.reshape(-1).astype(jnp.int32)
     n = flat.shape[0]
     npad = -(-n // tile) * tile - n

@@ -6,27 +6,27 @@ codeword boundary depends on the previous one — the RAW hazard cuSZ §V
 concedes.  The gap array breaks the chain: deflate records the bit offset
 at every `sub_size`-symbol boundary, so each subchunk decodes
 independently from its recorded start and the sequential walk shrinks to
-`sub_size` steps with `n_sub = chunk_size / sub_size` lanes running in
-lockstep.
+`sub_size` steps with one cursor per subchunk running in lockstep.
 
-One chunk per grid step; inside the kernel all `n_sub` subchunk cursors
-advance together.  Per step, for each cursor:
+Each grid step takes whole chunks (`common.chunks_per_tile`): their
+words as rows of 128 lanes and one cursor row per subchunk.  Per step of
+the walk, for every cursor:
 
-  1. fetch the two words straddling the cursor's bit position via ONE-HOT
-     CONTRACTIONS over the word index (the repo's standing MXU idiom —
-     int32 matmuls are bit-exact, and an out-of-range index matches no
-     one-hot row, yielding 0 exactly like a zero-padded stream);
+  1. fetch the word under the cursor and the word after it (0 past the
+     end of its chunk): a ONE-HOT CONTRACTION over the step's word rows
+     picks the cursor's row of word bytes (int8 x int8 -> int32, exact,
+     see `kernels.common`), then a lane mask picks the word in the row;
   2. splice the 32-bit left-aligned peek window;
   3. canonical length-interval compare: left-aligned code intervals tile
      [0, 2^32) contiguously in length order, so
      `len = 1 + sum_l lmask[l] * [peek >= thresh[l]]` — no LUT in VMEM
-     (the dense LUT would be a 2^16-entry gather; the compare is ~32
-     lane-ops and serves every max-length regime);
-  4. index the canonical symbol table, again via one-hot contraction.
+     (the compare serves every max-length regime);
+  4. look up the canonical symbol with lane masks over the tables.
 
-Emitted symbols land in a [n_sub, sub_size] tile whose row-major reshape
-is exactly chunk order.  Bit-exact with `core.huffman.inflate_gap` (the
-vmapped jax reference of the same shape) and with the sequential decoder.
+Cursor c writes its i-th symbol to column i of row c, so the output rows
+in row-major order are chunk order.  Bit-exact with
+`core.huffman.inflate_gap` (the vmapped jax reference of the same shape)
+and with the sequential decoder.
 """
 from __future__ import annotations
 
@@ -38,71 +38,83 @@ from jax.experimental import pallas as pl
 
 from repro.core import huffman as hf
 
-_TB = 64                     # padded table-row lanes (MAXLEN + 1 = 33)
+from .. import common
+
+_TB = 128                    # padded length-table lanes (MAXLEN + 1 = 33)
+_SIGN = -(2 ** 31)           # xor turns unsigned order into signed order
 
 
-def _gather_i32(idx, table_row):
-    """table_row[idx] for a vector of indices, as a one-hot int32 matmul.
-
-    idx: [n] int32; table_row: [T] int32.  Out-of-range idx -> 0."""
-    n = idx.shape[0]
-    t = table_row.shape[0]
-    iota = jax.lax.broadcasted_iota(jnp.int32, (n, t), 1)
-    oh = (idx[:, None] == iota).astype(jnp.int32)
-    return jax.lax.dot_general(oh, table_row[:, None],
-                               (((1,), (0,)), ((), ())),
-                               preferred_element_type=jnp.int32)[:, 0]
+def _pick(mask, table):
+    """Per-row sum of `table` where `mask` (one hit per row) -> [rows, 1]."""
+    return jnp.sum(jnp.where(mask, table, 0), axis=1, keepdims=True)
 
 
-def _inflate_kernel(sub, n_sub, words_ref, gaps_ref, nv_ref, thresh_ref,
-                    lmask_ref, fcode_ref, sidx_ref, scanon_ref, out_ref):
-    W = n_sub * sub
-    wrow = words_ref[...].reshape(-1).astype(jnp.int32)       # [W] bit-cast
-    gaps = gaps_ref[...].reshape(-1).astype(jnp.int32)        # [n_sub]
-    nv = nv_ref[0, 0]
-    thresh = thresh_ref[...].reshape(-1)                      # [TB] uint32
-    lmask = lmask_ref[...].reshape(-1)                        # [TB] int32
-    fcode = fcode_ref[...].reshape(-1).astype(jnp.int32)      # [TB] bit-cast
-    sidx = sidx_ref[...].reshape(-1)                          # [TB] int32
-    scanon = scanon_ref[...].reshape(-1)                      # [K] int32
-    base = jnp.arange(n_sub, dtype=jnp.int32) * sub
+def _inflate_kernel(chunk, sub, words_ref, cursor_ref, thresh_ref, lmask_ref,
+                    fcode_ref, sidx_ref, scanon_ref, out_ref):
+    rows = words_ref.shape[0]
+    rc, n_sub = chunk // 128, chunk // sub
+    ncur = rows * 128 // sub
+    # this step's cursor row: start bit offsets, then symbol counts
+    cursors = cursor_ref[pl.ds(pl.program_id(0) % 8, 1), :]
+    start = cursors[:, :ncur].reshape(ncur, 1)
+    count = cursors[:, ncur:].reshape(ncur, 1)
+    x = words_ref[...]                                        # [R, 128]
+    row = jax.lax.broadcasted_iota(jnp.int32, (rows, 128), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (rows, 128), 1)
+    below = jnp.concatenate([x[1:], jnp.zeros((1, 128), x.dtype)], axis=0)
+    nxt = jnp.concatenate([x[:, 1:], below[:, :1]], axis=1)   # next word
+    nxt = jnp.where((row % rc == rc - 1) & (lane == 127), 0, nxt)
+    table = jnp.concatenate(common.to_bytes(x) + common.to_bytes(nxt),
+                            axis=1)                           # [R, 1024]
+    cur_row0 = (jax.lax.broadcasted_iota(jnp.int32, (ncur, 1), 0)
+                // n_sub) * rc                                # chunk's row 0
+    row_iota = jax.lax.broadcasted_iota(jnp.int32, (ncur, rows), 1)
+    lane_iota = jax.lax.broadcasted_iota(jnp.int32, (ncur, 128), 1)
+    tb_iota = jax.lax.broadcasted_iota(jnp.int32, (ncur, _TB), 1)
+    sym_iota = jax.lax.broadcasted_iota(
+        jnp.int32, (ncur, scanon_ref.shape[1]), 1)
+    out_iota = jax.lax.broadcasted_iota(jnp.int32, (ncur, sub), 1)
+    thresh = thresh_ref[...]
+    live = lmask_ref[...] > 0
+    fcode, sidx, scanon = fcode_ref[...], sidx_ref[...], scanon_ref[...]
+    k = scanon_ref.shape[1]
 
     def step(i, carry):
         bitpos, out = carry
         wi = bitpos >> 5
-        bo = (bitpos & 31).astype(jnp.uint32)
-        cur = _gather_i32(wi, wrow).astype(jnp.uint32) << bo
-        nxt_w = _gather_i32(wi + 1, wrow).astype(jnp.uint32)
-        nxt = jnp.where(bo > 0, nxt_w >> (jnp.uint32(32) - bo),
-                        jnp.uint32(0))
-        peek = cur | nxt                  # 32-bit left-aligned window
-        hit = (peek[:, None] >= thresh[None, :]) & (lmask[None, :] > 0)
-        ln = 1 + jnp.sum(hit.astype(jnp.int32), axis=1)
+        bo = bitpos & 31
+        oh = (row_iota == cur_row0 + (wi >> 7)) & (wi < chunk)  # [ncur, R]
+        got = common.dot_i8(oh, table)                        # [ncur, 1024]
+        at = lane_iota == (wi & 127)
+        byte = [_pick(at, got[:, 128 * j:128 * (j + 1)]) for j in range(8)]
+        cur = common.from_bytes(byte[:4])
+        nxt_w = common.from_bytes(byte[4:])
+        peek = (cur << bo) | jnp.where(
+            bo > 0, jax.lax.shift_right_logical(nxt_w, 32 - bo), 0)
+        hit = ((peek ^ _SIGN) >= thresh) & live               # [ncur, TB]
+        ln = 1 + jnp.sum(hit.astype(jnp.int32), axis=1, keepdims=True)
         lnc = jnp.clip(ln, 1, hf.MAXLEN)
-        code = peek >> (jnp.uint32(32) - lnc.astype(jnp.uint32))
-        fc = _gather_i32(lnc, fcode)
-        si = _gather_i32(lnc, sidx)
-        idx = si + code.astype(jnp.int32) - fc
-        sym = _gather_i32(jnp.clip(idx, 0, scanon.shape[0] - 1), scanon)
-        ok = (base + i) < nv
-        out = jax.lax.dynamic_update_slice(
-            out, jnp.where(ok, sym, 0)[:, None], (0, i))
+        code = jax.lax.shift_right_logical(peek, 32 - lnc)
+        at_len = tb_iota == lnc
+        idx = _pick(at_len, sidx) + code - _pick(at_len, fcode)
+        sym = _pick(sym_iota == jnp.clip(idx, 0, k - 1), scanon)
+        ok = i < count
+        out = jnp.where(out_iota == i, jnp.where(ok, sym, 0), out)
         return bitpos + jnp.where(ok, ln, 0), out
 
     _, out = jax.lax.fori_loop(
-        0, sub, step,
-        (gaps, jnp.zeros((n_sub, sub), jnp.int32)))
-    out_ref[...] = out.reshape(out_ref.shape)   # [n_sub, sub] -> chunk order
+        0, sub, step, (start, jnp.zeros((ncur, sub), jnp.int32)))
+    out_ref[...] = out
 
 
-def _pad_row(x, n, dtype):
-    x = jnp.asarray(x, dtype)
+def _row(x, n, dtype):
+    x = jnp.asarray(x).astype(dtype)
     return jnp.pad(x, (0, n - x.shape[0]))[None, :]
 
 
 def inflate_pallas(words: jax.Array, n_valid: jax.Array, gap_bits: jax.Array,
-                   table: hf.DecodeTable, sub_size: int,
-                   interpret: bool = True) -> jax.Array:
+                   table: hf.DecodeTable, sub_size: int, *,
+                   interpret: bool) -> jax.Array:
     """words: [nc, W] uint32, n_valid: [nc], gap_bits: [nc, W//sub_size].
     Returns codes [nc, W] int32 (chunk order)."""
     nc, W = words.shape
@@ -110,26 +122,37 @@ def inflate_pallas(words: jax.Array, n_valid: jax.Array, gap_bits: jax.Array,
     if n_sub * sub_size != W:
         raise ValueError(f"gap array [{nc}, {n_sub}] does not tile chunks "
                          f"of {W} symbols with sub_size={sub_size}")
+    g = common.chunks_per_tile(W, sub_size)
+    ncp = -(-nc // g) * g
+    steps = ncp // g
+    rows, ncur = g * (W // 128), g * n_sub
+    words = jnp.pad(jax.lax.bitcast_convert_type(words, jnp.int32),
+                    ((0, ncp - nc), (0, 0))).reshape(-1, 128)
+    base = jnp.arange(n_sub, dtype=jnp.int32) * sub_size
+    count = jnp.clip(n_valid.astype(jnp.int32)[:, None] - base, 0, sub_size)
+    # one row of cursor state per step, 8 steps to a block (the (8, 128)
+    # rule); padded chunks decode nothing (count 0)
+    cursors = jnp.concatenate(
+        [jnp.pad(a, ((0, ncp - nc), (0, 0))).reshape(steps, ncur)
+         for a in (gap_bits.astype(jnp.int32), count)], axis=1)
+    cursors = jnp.pad(cursors, ((0, -steps % 8), (0, 0)))
     cb = table.cb
     k = cb.sym_canon.shape[0]
-    kp = -(-k // 128) * 128                     # lane-pad the symbol table
-    thresh = _pad_row(table.thresh, _TB, jnp.uint32)
-    lmask = _pad_row(table.lmask, _TB, jnp.int32)
-    fcode = _pad_row(cb.first_code, _TB, jnp.uint32)
-    sidx = _pad_row(cb.start_idx, _TB, jnp.int32)
-    scanon = _pad_row(cb.sym_canon, kp, jnp.int32)
-    tspec = pl.BlockSpec((1, _TB), lambda i: (0, 0))
-    return pl.pallas_call(
-        functools.partial(_inflate_kernel, sub_size, n_sub),
-        grid=(nc,),
-        in_specs=[pl.BlockSpec((1, W), lambda i: (i, 0)),
-                  pl.BlockSpec((1, n_sub), lambda i: (i, 0)),
-                  pl.BlockSpec((1, 1), lambda i: (i, 0)),
-                  tspec, tspec, tspec, tspec,
-                  pl.BlockSpec((1, kp), lambda i: (0, 0))],
-        out_specs=pl.BlockSpec((1, W), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((nc, W), jnp.int32),
+    thresh = jax.lax.bitcast_convert_type(
+        table.thresh.astype(jnp.uint32), jnp.int32) ^ _SIGN
+    fcode = jax.lax.bitcast_convert_type(cb.first_code.astype(jnp.uint32),
+                                         jnp.int32)
+    tables = [_row(thresh, _TB, jnp.int32), _row(table.lmask, _TB, jnp.int32),
+              _row(fcode, _TB, jnp.int32), _row(cb.start_idx, _TB, jnp.int32),
+              _row(cb.sym_canon, -(-k // 128) * 128, jnp.int32)]
+    full = [pl.BlockSpec(t.shape, lambda i: (0, 0)) for t in tables]
+    out = pl.pallas_call(
+        functools.partial(_inflate_kernel, W, sub_size),
+        grid=(steps,),
+        in_specs=[pl.BlockSpec((rows, 128), lambda i: (i, 0)),
+                  pl.BlockSpec((8, 2 * ncur), lambda i: (i // 8, 0))] + full,
+        out_specs=pl.BlockSpec((ncur, sub_size), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((ncp * n_sub, sub_size), jnp.int32),
         interpret=interpret,
-    )(words, gap_bits.astype(jnp.int32),
-      n_valid.astype(jnp.int32).reshape(nc, 1),
-      thresh, lmask, fcode, sidx, scanon)
+    )(words, cursors, *tables)
+    return out.reshape(ncp, W)[:nc]

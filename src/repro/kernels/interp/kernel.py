@@ -59,11 +59,11 @@ def _run(kern_fn, pe: jax.Array, other: jax.Array,
     return out[:rows]
 
 
-def residual_rows_pallas(pe: jax.Array, odd: jax.Array,
-                         interpret: bool = True) -> jax.Array:
+def residual_rows_pallas(pe: jax.Array, odd: jax.Array, *,
+                         interpret: bool) -> jax.Array:
     return _run(_residual_kernel, pe, odd, interpret)
 
 
-def odd_rows_pallas(pe: jax.Array, resid: jax.Array,
-                    interpret: bool = True) -> jax.Array:
+def odd_rows_pallas(pe: jax.Array, resid: jax.Array, *,
+                    interpret: bool) -> jax.Array:
     return _run(_odd_kernel, pe, resid, interpret)

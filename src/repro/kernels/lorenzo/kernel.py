@@ -7,80 +7,88 @@ the grid is embarrassingly parallel exactly like the paper's CUDA blocks.
 
 One HBM->VMEM read of the f32 tile produces both int32 outputs in a single
 fused pass (the paper's motivation: the stage is memory-bound, so fusing
-prequant/predict/postquant maximizes bandwidth utilization).  Tiles default
-to lane-aligned shapes ((8,128) multiples for f32/int32).
+prequant/predict/postquant maximizes bandwidth utilization).
 
 The reverse kernel computes the in-block N-D inclusive prefix sum (the
-cumsum inverse) + dequant, also one pass.
+cumsum inverse) + dequant, also one pass.  Mosaic has no `cumsum`, so the
+prefix sum is a log-step (Hillis-Steele) scan of zero-filled shifted adds
+along each block axis: ceil(log2 b) int32 adds per axis, exact.
+
+Layout: each block is one LANE ROW of prod(block) values in row-major
+order ([nblk, 512] for the paper's 8x8x8 blocks), so tiles are lane-dense
+and the flat code stream reshapes to it for free.  Block axis j becomes
+lane stride s_j = prod(block[j+1:]); a shift along it is a lane shift by
+k·s_j, masked to zero where it would cross the axis' edge (the zero
+padding layer).  ([nblk, 8, 8, 8] tiles would pad every 8-lane row to
+128 lanes, and the relayout feeding them takes the TPU compiler minutes
+on the decompress path.)  The grid walks `_TILE_ELEMS`-sized
+tiles of whole blocks; a partial last tile is fine because blocks are
+independent.
 """
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .. import common
 
-def _shift1(x, axis):
-    """In-tile shift-by-one with zero fill (the padding layer)."""
-    zshape = list(x.shape)
-    zshape[axis] = 1
-    z = jnp.zeros(zshape, x.dtype)
-    sl = [slice(None)] * x.ndim
-    sl[axis] = slice(0, x.shape[axis] - 1)
-    return jnp.concatenate([z, x[tuple(sl)]], axis=axis)
+_TILE_ELEMS = 128 * 1024     # values per grid step (512 KB of int32)
 
 
-def _dualquant_kernel(nd, nbins, eb, x_ref, codes_ref, delta_ref):
+def _axis_shift(x, block, ax, k):
+    """`x` [T, prod(block)] shifted by `k` along block axis `ax`, zero
+    where the shift crosses the block's edge."""
+    stride = math.prod(block[ax + 1:])
+    pos = (jax.lax.broadcasted_iota(jnp.int32, x.shape, 1) // stride) \
+        % block[ax]
+    return jnp.where(pos >= k, common.shift(x, 1, k * stride), 0)
+
+
+def _dualquant_kernel(block, nbins, eb, x_ref, codes_ref, delta_ref):
     x = x_ref[...]
     dq = jnp.rint(x / (2.0 * eb)).astype(jnp.int32)           # PREQUANT
     # (same division form as the oracle: reciprocal-multiply would flip
     # rint ties and break bit-equality with ref.py)
     delta = dq
-    for ax in range(x.ndim - nd, x.ndim):                     # ℓ-delta
-        delta = delta - _shift1(delta, ax)
+    for ax in range(len(block)):                              # ℓ-delta
+        delta = delta - _axis_shift(delta, block, ax, 1)
     radius = nbins // 2                                       # POSTQUANT
     in_cap = (delta > -radius) & (delta < radius)
     codes_ref[...] = jnp.where(in_cap, delta + radius, 0).astype(jnp.int32)
     delta_ref[...] = delta
 
 
-def _reverse_kernel(nd, eb, delta_ref, out_ref):
+def _reverse_kernel(block, eb, delta_ref, out_ref):
     d = delta_ref[...]
-    for ax in range(d.ndim - nd, d.ndim):                     # cumsum inverse
-        d = jnp.cumsum(d, axis=ax, dtype=jnp.int32)
+    for ax in range(len(block)):                              # cumsum inverse
+        k = 1
+        while k < block[ax]:
+            d = d + _axis_shift(d, block, ax, k)
+            k *= 2
     out_ref[...] = d.astype(jnp.float32) * (2.0 * eb)
 
 
-def _grid_and_specs(xb_shape, nd, blocks_per_tile):
-    """Grid over leading block axes; each tile carries `blocks_per_tile`
-    blocks on the first block axis to keep VMEM tiles lane/sublane aligned
-    even for small paper blocks (e.g. 8x8x8)."""
-    nblk = xb_shape[:len(xb_shape) - nd]
-    blk = xb_shape[len(xb_shape) - nd:]
-    flat = 1
-    for b in nblk:
-        flat *= b
-    bpt = min(blocks_per_tile, flat)
-    while flat % bpt:
-        bpt -= 1
-    grid = (flat // bpt,)
-    tile = (bpt,) + blk
-    def idx(i):
-        return (i,) + (0,) * nd
-    spec = pl.BlockSpec((bpt,) + blk, idx)
-    return grid, tile, spec, (flat,) + blk
+def _rows_and_spec(xb_shape, nd):
+    """(flat [nblk, prod(block)] shape, block dims, grid, BlockSpec)."""
+    block = tuple(xb_shape[len(xb_shape) - nd:])
+    nblk = math.prod(xb_shape[:len(xb_shape) - nd])
+    width = math.prod(block)
+    rows = max(8, _TILE_ELEMS // width // 8 * 8)
+    rows = nblk if nblk <= rows else rows
+    spec = pl.BlockSpec((rows, width), lambda i: (i, 0))
+    return (nblk, width), block, (pl.cdiv(nblk, rows),), spec
 
 
-def dualquant_blocks_pallas(xb: jax.Array, eb: float, nbins: int,
-                            blocks_per_tile: int = 64,
-                            interpret: bool = True):
+def dualquant_blocks_pallas(xb: jax.Array, eb: float, nbins: int, *,
+                            interpret: bool):
     """xb: [nb..., b...] float32 blocked input (block axes last nd)."""
-    nd = xb.ndim // 2
-    grid, tile, spec, flat_shape = _grid_and_specs(xb.shape, nd, blocks_per_tile)
+    flat_shape, block, grid, spec = _rows_and_spec(xb.shape, xb.ndim // 2)
     xf = xb.reshape(flat_shape)
-    kern = functools.partial(_dualquant_kernel, nd, nbins, eb)
+    kern = functools.partial(_dualquant_kernel, block, nbins, eb)
     codes, delta = pl.pallas_call(
         kern,
         grid=grid,
@@ -93,13 +101,12 @@ def dualquant_blocks_pallas(xb: jax.Array, eb: float, nbins: int,
     return codes.reshape(xb.shape), delta.reshape(xb.shape)
 
 
-def reverse_blocks_pallas(delta: jax.Array, eb: float,
-                          blocks_per_tile: int = 64,
-                          interpret: bool = True) -> jax.Array:
-    nd = delta.ndim // 2
-    grid, tile, spec, flat_shape = _grid_and_specs(delta.shape, nd, blocks_per_tile)
+def reverse_blocks_pallas(delta: jax.Array, eb: float, *,
+                          interpret: bool) -> jax.Array:
+    flat_shape, block, grid, spec = _rows_and_spec(delta.shape,
+                                                   delta.ndim // 2)
     df = delta.reshape(flat_shape)
-    kern = functools.partial(_reverse_kernel, nd, eb)
+    kern = functools.partial(_reverse_kernel, block, eb)
     out = pl.pallas_call(
         kern,
         grid=grid,

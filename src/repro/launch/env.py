@@ -15,8 +15,9 @@ and deduplicating ``XLA_FLAGS`` against whatever is already set), so
 tests assert on it without touching the process environment.
 `setup_runtime` applies it to ``os.environ`` — call it **before the
 first JAX backend touch** (importing jax is fine; creating arrays is
-not), since XLA reads these at backend initialization.  Importing this
-module never mutates the environment.
+not), since XLA reads these at backend initialization — and turns on
+JAX's persistent compilation cache (`compile_cache_dir`).  Importing
+this module never mutates the environment.
 """
 from __future__ import annotations
 
@@ -90,6 +91,32 @@ def env_overrides(cfg: RuntimeConfig,
     return out
 
 
+#: JAX's own environment variable for its persistent compilation cache
+CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+#: the cache directory when CACHE_ENV is unset: fixed, inside the
+#: checkout (git-ignored), so every run of this checkout hits it
+CHECKOUT_CACHE = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))), ".jax_cache")
+
+
+def compile_cache_dir(base_env: Optional[Dict[str, str]] = None) -> str:
+    """Where compiled executables persist: ``$JAX_COMPILATION_CACHE_DIR``
+    when set (JAX reads it itself), else `CHECKOUT_CACHE`."""
+    base_env = os.environ if base_env is None else base_env
+    return base_env.get(CACHE_ENV) or CHECKOUT_CACHE
+
+
+def enable_compile_cache() -> str:
+    """Turn on the persistent compilation cache; returns its directory.
+    Sets a directory in code only when the environment names none."""
+    import jax
+    path = compile_cache_dir()
+    if not os.environ.get(CACHE_ENV):
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
 def _backends_initialized() -> bool:
     xb = sys.modules.get("jax._src.xla_bridge")
     return bool(getattr(xb, "_backends", None))
@@ -115,6 +142,7 @@ def setup_runtime(cfg: Optional[RuntimeConfig] = None, **kw) -> RuntimeConfig:
         # env var alone is too late once jax.config snapshotted it
         sys.modules["jax"].config.update("jax_debug_nans",
                                          bool(cfg.nan_debug))
+    enable_compile_cache()
     return cfg
 
 

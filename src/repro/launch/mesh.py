@@ -23,3 +23,15 @@ def make_host_mesh():
     """1-device mesh with the production axis names (tests/examples)."""
     return jax.make_mesh((1, 1), ("data", "model"),
                          axis_types=(jax.sharding.AxisType.Auto,) * 2)
+
+
+def make_local_mesh():
+    """Two pods over every local device: ``(2, n // 2, 1)`` on
+    ('pod', 'data', 'model') for ``n = jax.device_count()`` — four chips
+    of one host become two 2-chip pods, the smallest layout on which the
+    compressed cross-pod gradient all-reduce runs."""
+    n = jax.device_count()
+    if n % 2:
+        raise ValueError(f"{n} local devices do not split into 2 pods")
+    return jax.make_mesh((2, n // 2, 1), ("pod", "data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 3)

@@ -25,10 +25,10 @@ import numpy as np
 
 from repro import configs
 from repro.launch import env as launch_env
-from repro.models import model as M
 from repro.serve.engine import (LAST_HANDOFF_STATS, LAST_RESHARD_STATS,
                                 ServeConfig, decode_tokens, encode_handoff,
-                                generate, prefill, reshard_caches)
+                                generate, load_params, prefill,
+                                reshard_caches)
 
 
 def main():
@@ -70,15 +70,15 @@ def main():
 
     launch_env.setup_runtime(launch_env.from_args(args))
     cfg = configs.reduced(args.arch) if args.reduced else configs.get(args.arch)
-    params = M.init_params(jax.random.PRNGKey(0), cfg)
-    rng = np.random.default_rng(0)
-    prompt = jnp.asarray(rng.integers(0, cfg.vocab,
-                                      (args.batch, args.prompt_len))
-                         .astype(np.int32))
     scfg = ServeConfig(
         s_max=args.s_max,
         compressed_kv=args.compressed_kv or args.continuous,
         kv_codec=args.kv_codec, temperature=args.temperature)
+    params = load_params(jax.random.PRNGKey(0), cfg, scfg)
+    rng = np.random.default_rng(0)
+    prompt = jnp.asarray(rng.integers(0, cfg.vocab,
+                                      (args.batch, args.prompt_len))
+                         .astype(np.int32))
 
     if args.continuous:
         from repro.serve import scheduler as sched_mod
@@ -106,7 +106,9 @@ def main():
               f"{sched.preemptions} evicted={st['evicted_pages']} "
               f"restored={st['restored_pages']} "
               f"peak_pages={st['peak_used']} "
-              f"evict_codec={st['evict_codec']}")
+              f"evict_codec={st['evict_codec']} "
+              f"lossless_fallbacks={st['lossless_fallbacks']} "
+              f"nonfinite_logits={sched.nonfinite_logits}")
         print(f"generated {total} tokens in {dt:.2f}s "
               f"({total / dt:.1f} tok/s incl. compile)")
         return

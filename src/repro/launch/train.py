@@ -5,12 +5,15 @@
 
 On the production mesh (--mesh single|multi) the same script shards
 params/optimizer/batch per repro.dist.sharding and runs the jitted step;
---reduced + --mesh host runs a real loop on this container's single CPU
-device.  --lower-only stops after compile (the dry-run path with real
-shapes)."""
+--reduced + --mesh host runs a real loop on one device, and --mesh local
+splits every local device into two pods (four chips: the compressed
+cross-pod gradient all-reduce on one host).  --layers keeps the published
+widths and cuts the depth.  --lower-only stops after compile (the
+dry-run path with real shapes)."""
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import time
 
 import jax
@@ -25,17 +28,23 @@ from repro.dist import sharding as SH
 from repro.dist.context import use_mesh, use_param_specs
 from repro.io import checkpoint as ckpt_io
 from repro.launch import env as launch_env
-from repro.launch.mesh import make_host_mesh, make_production_mesh
+from repro.launch.mesh import (make_host_mesh, make_local_mesh,
+                               make_production_mesh)
 from repro.models import model as M
 from repro.optim import adamw
 from repro.train.train_step import TrainConfig, make_train_step
 
 
-def main():
+def main(argv=None):
+    """Run the launcher on `argv` (default: the command line); returns
+    the loss of every step that ran (empty under --lower-only)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true")
-    ap.add_argument("--mesh", default="host", choices=["host", "single", "multi"])
+    ap.add_argument("--layers", type=int, default=None,
+                    help="cut the depth to N layers, widths unchanged")
+    ap.add_argument("--mesh", default="host",
+                    choices=["host", "local", "single", "multi"])
     ap.add_argument("--steps", type=int, default=20)
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=128)
@@ -63,12 +72,16 @@ def main():
                     help="arm the straggler MitigationPolicy (rebalance/"
                          "exclude flagged hosts, skip NaN steps)")
     launch_env.add_arguments(ap)
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     launch_env.setup_runtime(launch_env.from_args(args))
     cfg = configs.reduced(args.arch) if args.reduced else configs.get(args.arch)
-    mesh = make_host_mesh() if args.mesh == "host" else \
-        make_production_mesh(multi_pod=args.mesh == "multi")
+    if args.layers is not None:
+        cfg = dataclasses.replace(cfg, n_layers=args.layers)
+    mesh = {"host": make_host_mesh, "local": make_local_mesh,
+            "single": make_production_mesh,
+            "multi": lambda: make_production_mesh(multi_pod=True)
+            }[args.mesh]()
     npods = mesh.shape.get("pod", 1)
     tcfg = TrainConfig(
         microbatches=args.microbatches, grad_compress=args.grad_compress,
@@ -96,7 +109,7 @@ def main():
             c = jax.jit(make_train_step(cfg, tcfg)).lower(
                 M.param_shapes(cfg), opt_shapes, toks).compile()
             print("lowered+compiled OK;", c.memory_analysis())
-            return
+            return []
         params = M.init_params(jax.random.PRNGKey(0), cfg)
         params = jax.device_put(params, pshard)
         opt = adamw.init(params, tcfg.adamw)
@@ -114,6 +127,7 @@ def main():
         policy = (fault.MitigationPolicy(
                       chaos_cfg.nhosts if chaos_cfg is not None else nhosts)
                   if args.mitigate else None)
+        losses = []
         try:
             with chaos.use_chaos(chaos_cfg) as monkey:
                 for step in range(start, args.steps):
@@ -134,6 +148,7 @@ def main():
                         policy.on_bad_loss(step, float("nan"))
                         print(f"step {step:5d}  skipped (bad loss)")
                         continue
+                    losses.append(float(loss))
                     if step % 5 == 0 or step == args.steps - 1:
                         tps = args.batch * args.seq / dt
                         extra = ""
@@ -141,7 +156,7 @@ def main():
                                                    or policy.events):
                             extra = (f"  shares={[round(float(s), 3) for s in policy.shares]}"
                                      f"  excluded={sorted(policy.excluded)}")
-                        print(f"step {step:5d}  loss {float(loss):.4f}  "
+                        print(f"step {step:5d}  loss {losses[-1]:.4f}  "
                               f"{dt * 1e3:7.1f} ms  {tps:9.0f} tok/s{extra}")
                     if args.checkpoint_dir and (step + 1) % args.checkpoint_every == 0:
                         ckpt_io.save_checkpoint(
@@ -151,6 +166,7 @@ def main():
         finally:
             if writer is not None:
                 writer.close()     # drain + surface any async write failure
+        return losses
 
 
 if __name__ == "__main__":

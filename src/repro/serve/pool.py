@@ -126,6 +126,9 @@ class PagedKVPool:
         self.restored_pages = 0
         self.peak_used = 0
         self.host_bytes = 0               # current, not monotonic
+        #: evicted slabs the codec could not hold within its bound, so
+        #: they went to host raw ("lossless") instead
+        self.lossless_fallbacks = 0
 
     # -- allocator ----------------------------------------------------------
 
@@ -244,6 +247,9 @@ class PagedKVPool:
         self._release_pid(page.pid)
         page.pid = None
         self.evicted_pages += 1
+        self.lossless_fallbacks += sum(
+            p.header.codec != self.evict_codec
+            for parts in page.host for p in parts)
         self.host_bytes += _host_nbytes(page.host)
         return True
 
@@ -314,6 +320,7 @@ class PagedKVPool:
                 "host_bytes": self.host_bytes,
                 "evicted_pages": self.evicted_pages,
                 "restored_pages": self.restored_pages,
+                "lossless_fallbacks": self.lossless_fallbacks,
                 "evict_codec": self.evict_codec,
                 "sequences": len(self._tables)}
 

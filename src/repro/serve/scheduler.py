@@ -82,7 +82,8 @@ def make_batch_step(cfg: ModelConfig, scfg: E.ServeConfig,
                     max_batch: int):
     """One-token decode for `max_batch` ragged slots: vmap over the
     batch axis with a PER-SLOT cache_len, so each lane attends to its
-    own prefix while retired/empty lanes run harmlessly at len 0."""
+    own prefix while retired/empty lanes run harmlessly at len 0.
+    Returns (next tokens [B], per-slot all-logits-finite [B], caches)."""
 
     def batch_step(params, tokens, caches, lens, key):
         # body runs only while tracing, so this counts (re)traces
@@ -99,14 +100,15 @@ def make_batch_step(cfg: ModelConfig, scfg: E.ServeConfig,
                 compute_dtype=scfg.compute_dtype,
                 compressed_kv=scfg.compressed_kv)
             nt = E.pick_token(logits[:, -1, :], kk, scfg)[0]
-            return nt, jax.tree_util.tree_map(lambda x: jnp.squeeze(x, 1),
-                                              nc.entries)
+            return nt, jnp.all(jnp.isfinite(logits)), \
+                jax.tree_util.tree_map(lambda x: jnp.squeeze(x, 1),
+                                       nc.entries)
 
         keys = jax.random.split(key, tokens.shape[0])
-        nt, entries = jax.vmap(one, in_axes=(0, 1, 0, 0),
-                               out_axes=(0, 1))(tokens, caches.entries,
-                                                lens, keys)
-        return nt, M.DecodeCaches(entries)
+        nt, finite, entries = jax.vmap(
+            one, in_axes=(0, 1, 0, 0), out_axes=(0, 0, 1))(
+                tokens, caches.entries, lens, keys)
+        return nt, finite, M.DecodeCaches(entries)
 
     return batch_step
 
@@ -240,6 +242,8 @@ class ContinuousScheduler:
         self._admit_counter = 0
         self.n_steps = 0
         self.preemptions = 0
+        #: prefills and live decode slots whose logits held a NaN or inf
+        self.nonfinite_logits = 0
         self.occupancy_samples: List[float] = []
 
     # -- admission ---------------------------------------------------------
@@ -256,6 +260,7 @@ class ContinuousScheduler:
                                        self.scfg)
         self.key, k0 = jax.random.split(self.key)
         t0 = int(E.pick_token(last, k0, self.scfg)[0])  # repro-lint: allow[host-sync] admission needs the first sampled token on host to seed the slot
+        self.nonfinite_logits += int(not jnp.all(jnp.isfinite(last)))  # repro-lint: allow[host-sync] one flag per admission, beside the token readback above
 
         leaves = _attn_leaves(self.cfg, caches.entries)
         n_pages = KVC.kv_page_count(plen)
@@ -437,14 +442,16 @@ class ContinuousScheduler:
     def _step(self) -> None:
         self._grow_pages()
         self.key, k = jax.random.split(self.key)
-        nt, self.caches = self.step_fn(
+        nt, finite, self.caches = self.step_fn(
             self.params, self.tokens, self.caches,
             jnp.asarray(self.lens), k)
         self.n_steps += 1
-        nt_host = np.asarray(jax.device_get(nt))  # repro-lint: allow[host-sync] scheduler control flow (retire/admit) branches on the sampled tokens
+        # repro-lint: allow[host-sync] scheduler control flow (retire/admit) branches on the sampled tokens
+        nt_host, finite_host = jax.device_get((nt, finite))
         for slot, s in enumerate(self.slots):
             if s is None:
                 continue
+            self.nonfinite_logits += int(not finite_host[slot])
             s["generated"].append(s["next_token"])
             s["next_token"] = int(nt_host[slot])
             self.lens[slot] += 1

@@ -12,6 +12,7 @@ import time
 import warnings
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 import pytest
 
@@ -587,6 +588,28 @@ class TestLaunchEnv:
         cfg = E.RuntimeConfig(async_collectives=False)
         ov = E.env_overrides(cfg, base_env={"XLA_FLAGS": ""})
         assert ov == {}
+
+    def test_compile_cache_dir_prefers_the_environment(self):
+        from repro.launch import env as E
+        assert E.compile_cache_dir({E.CACHE_ENV: "/x/cache"}) == "/x/cache"
+        assert E.compile_cache_dir({}) == E.CHECKOUT_CACHE
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert E.CHECKOUT_CACHE == os.path.join(root, ".jax_cache")
+
+    def test_enable_compile_cache_sets_no_dir_when_env_names_one(
+            self, monkeypatch):
+        from repro.launch import env as E
+        prev = jax.config.jax_compilation_cache_dir
+        try:
+            jax.config.update("jax_compilation_cache_dir", None)
+            monkeypatch.setenv(E.CACHE_ENV, "/from/env")
+            assert E.enable_compile_cache() == "/from/env"
+            assert jax.config.jax_compilation_cache_dir is None
+            monkeypatch.delenv(E.CACHE_ENV)
+            assert E.enable_compile_cache() == E.CHECKOUT_CACHE
+            assert jax.config.jax_compilation_cache_dir == E.CHECKOUT_CACHE
+        finally:
+            jax.config.update("jax_compilation_cache_dir", prev)
 
     def test_from_args_round_trip(self):
         import argparse

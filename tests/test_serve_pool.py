@@ -249,3 +249,18 @@ def test_evict_codec_resolves_from_context_hook():
         assert PagedKVPool(2, evict_codec="int8-block"
                            ).evict_codec == "int8-block"
     assert PagedKVPool(2).evict_codec == "cusz"   # default past the scope
+
+
+@pytest.mark.parametrize("outlier_frac, fallbacks", [(1.0, 0), (0.0, 2)])
+def test_lossless_fallbacks_counted(outlier_frac, fallbacks):
+    """A cusz-evicted slab whose outliers overflow the codec's store
+    ships raw; the pool counts every such slab."""
+    qkv = _quantkv(jax.random.PRNGKey(4), 2)
+    pool = PagedKVPool(2, evict_codec="cusz", source_dtype=jnp.float32,
+                       evict_cfg={"eb": 1e-6, "eb_mode": "valrel",
+                                  "outlier_frac": outlier_frac})
+    pool.register("s")
+    for i in range(2):
+        pool.append_page("s", (KVC.kv_page_slice(qkv, SEQ_AXIS, i),))
+    pool.evict_sequence("s")
+    assert pool.stats()["lossless_fallbacks"] == fallbacks

@@ -182,6 +182,27 @@ class TestContinuousBeatsStatic:
         assert sc.n_steps < ss.n_steps, (sc.n_steps, ss.n_steps)
 
 
+class TestLogitHealth:
+    def test_finite_run_counts_nothing(self, setup):
+        cfg, params, scfg = setup
+        fin, sched = S.run_continuous(
+            params, cfg, scfg, S.SchedulerConfig(max_batch=2, pool_pages=8),
+            _requests(3, np.random.default_rng(7)))
+        assert len(fin) == 3 and sched.nonfinite_logits == 0
+
+    def test_nan_weights_are_counted_per_prefill_and_slot_step(self, setup):
+        cfg, params, scfg = setup
+        bad = dict(params, out_norm=jnp.full_like(params["out_norm"],
+                                                  jnp.nan))
+        req = S.Request(rid=0, prompt=np.arange(1, 6, dtype=np.int32),
+                        max_new=3)
+        _, sched = S.run_continuous(
+            bad, cfg, scfg, S.SchedulerConfig(max_batch=2, pool_pages=8),
+            [req])
+        # one prefill + one decode step per generated token after it
+        assert sched.nonfinite_logits == 1 + sched.n_steps
+
+
 class TestLifecycleAccounting:
     def test_pool_drains_and_eos_retires(self, setup):
         cfg, params, scfg = setup

@@ -188,10 +188,12 @@ class TestKernelParity:
                                        interpret=interp)
             np.testing.assert_array_equal(np.asarray(back), np.asarray(odd))
 
-    def test_bitshuffle_planes_parity_and_exact_inverse(self):
-        nbins, chunk = 1024, 256
+    # (300, 512) spans two grid steps of the kernel and pads the second
+    @pytest.mark.parametrize("nc,chunk", [(3, 256), (300, 512)])
+    def test_bitshuffle_planes_parity_and_exact_inverse(self, nc, chunk):
+        nbins = 1024
         rng = np.random.default_rng(13)
-        codes2 = jnp.asarray(rng.integers(0, nbins, (3, chunk)), jnp.int32)
+        codes2 = jnp.asarray(rng.integers(0, nbins, (nc, chunk)), jnp.int32)
         p_jax = bitshuffle_ops.encode_planes(codes2, nbins, impl="jax")
         p_pl = bitshuffle_ops.encode_planes(codes2, nbins, impl="pallas",
                                             interpret=True)

@@ -1,0 +1,209 @@
+"""Every pipeline-stage Pallas kernel compiles for a TPU v5e.
+
+Interpret mode cannot show what the chip's compiler (Mosaic) refuses:
+block shapes off the (8, 128) tiling, primitives it has no lowering for
+(`cumsum`, int32 matrix products), scoped-VMEM overruns.  These tests
+compile each kernel with ``interpret=False`` for a described ``v5e:2x2``
+topology, at the widths `chip_smoke.py` drives — Hurricane 100x500x500
+(paper Table 2) under cusz / cusz-i / fz, HACC 280,953,867 under cusz —
+and check that the kernel is in the executable.  Nothing runs; the
+interpret-mode parity tests check the results.
+
+The topology is described inside a module fixture (never at import), and
+every compile runs with the persistent compilation cache off: a compile
+for a described chip can be written to the cache but never read back.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from repro.core import dualquant as dq
+from repro.core import huffman as hf
+from repro.core import interp as IP
+from repro.kernels import dispatch
+from repro.kernels.bitshuffle import kernel as bitshuffle_k
+from repro.kernels.bitshuffle.ref import nplanes
+from repro.kernels.deflate import kernel as deflate_k
+from repro.kernels.encode import kernel as encode_k
+from repro.kernels.histogram import kernel as histogram_k
+from repro.kernels.inflate import kernel as inflate_k
+from repro.kernels.interp import kernel as interp_k
+from repro.kernels.lorenzo import kernel as lorenzo_k
+
+NBINS = 1024
+HURRICANE = (100, 500, 500)
+HACC = (280_953_867,)
+CHUNK, SUB, FZ_CHUNK = 4096, 128, 512
+
+
+def _blocked(shape):
+    block = dq.DEFAULT_BLOCKS[len(shape)]
+    nb = tuple(-(-s // b) for s, b in zip(shape, block))
+    return nb + block
+
+
+def _n_codes(shape):
+    n = 1
+    for s in _blocked(shape):
+        n *= s
+    return n
+
+
+def _interp_rows(shape):
+    """(rows, mo) of the first level step along each axis."""
+    steps, _ = IP.interp_plan(shape)
+    out, seen = [], set()
+    for axis, shp in steps:
+        if axis in seen:
+            continue
+        seen.add(axis)
+        rows = 1
+        for a, s in enumerate(shp):
+            if a != axis:
+                rows *= s
+        out.append((rows, (shp[axis] + 1) // 2, shp[axis] // 2))
+    return out
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:       # noqa: BLE001 - any failure means "cannot describe"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return jax.sharding.SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_cache():
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def spec(one_chip, no_cache):
+    def make(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+    return make
+
+
+@pytest.fixture(scope="module")
+def on_chip(one_chip):
+    def place(tree):
+        return jax.tree.map(
+            lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype,
+                                           sharding=one_chip), tree)
+    return place
+
+
+def _compiles(fn, *args):
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
+def _codebook_shapes(on_chip):
+    return on_chip(jax.eval_shape(hf.canonical_codebook,
+                                  jax.ShapeDtypeStruct((NBINS,), jnp.int32)))
+
+
+# one compile per (kernel, width); ids name the kernel as dispatch does
+def _lorenzo_dualquant(spec, on_chip):
+    for shape in (HURRICANE, HACC):
+        _compiles(lambda x: lorenzo_k.dualquant_blocks_pallas(
+            x, 1e-3, NBINS, interpret=False),
+            spec(_blocked(shape), jnp.float32))
+
+
+def _lorenzo_reverse(spec, on_chip):
+    for shape in (HURRICANE, HACC):
+        _compiles(lambda d: lorenzo_k.reverse_blocks_pallas(
+            d, 1e-3, interpret=False), spec(_blocked(shape)))
+
+
+def _histogram(spec, on_chip):
+    _compiles(lambda c: histogram_k.histogram_pallas(
+        c, NBINS, interpret=False), spec((_n_codes(HACC),)))
+
+
+def _encode(spec, on_chip):
+    cb = _codebook_shapes(on_chip)
+    _compiles(lambda c, b: encode_k.encode_pallas(c, b, interpret=False),
+              spec((_n_codes(HACC),)), cb)
+
+
+def _deflate(spec, on_chip):
+    n = _n_codes(HACC)
+    for chunk in (CHUNK, FZ_CHUNK):
+        _compiles(lambda cw, bw: deflate_k.deflate_pallas(
+            cw, bw, chunk, SUB, interpret=False),
+            spec((n,), jnp.uint32), spec((n,)))
+
+
+def _inflate(spec, on_chip):
+    nc = -(-_n_codes(HACC) // CHUNK)
+    for ml in (hf.LUT_BITS, hf.MAXLEN):
+        table = on_chip(jax.eval_shape(
+            lambda l, ml=ml: hf.build_decode_table(l, ml),
+            jax.ShapeDtypeStruct((NBINS,), jnp.int32)))
+        _compiles(lambda w, nv, g, t: inflate_k.inflate_pallas(
+            w, nv, g, t, SUB, interpret=False),
+            spec((nc, CHUNK), jnp.uint32), spec((nc,)),
+            spec((nc, CHUNK // SUB)), table)
+
+
+def _interp(fn):
+    def run(spec, on_chip):
+        for rows, me, mo in _interp_rows(HURRICANE):
+            _compiles(lambda pe, o: fn(pe, o, interpret=False),
+                      spec((rows, me + 3)), spec((rows, mo)))
+    return run
+
+
+def _bitshuffle_encode(spec, on_chip):
+    nc = -(-_n_codes(HURRICANE) // FZ_CHUNK)
+    _compiles(lambda c: bitshuffle_k.encode_planes_pallas(
+        c, NBINS, interpret=False), spec((nc, FZ_CHUNK)))
+
+
+def _bitshuffle_decode(spec, on_chip):
+    nc = -(-_n_codes(HURRICANE) // FZ_CHUNK)
+    _compiles(lambda p: bitshuffle_k.decode_planes_pallas(
+        p, NBINS, interpret=False),
+        spec((nc, nplanes(NBINS), FZ_CHUNK // 32), jnp.uint32))
+
+
+CASES = {
+    "lorenzo.dualquant": _lorenzo_dualquant,
+    "lorenzo.reverse": _lorenzo_reverse,
+    "histogram": _histogram,
+    "encode": _encode,
+    "deflate": _deflate,
+    "inflate": _inflate,
+    "interp.predict": _interp(interp_k.residual_rows_pallas),
+    "interp.reconstruct": _interp(interp_k.odd_rows_pallas),
+    "bitshuffle.encode": _bitshuffle_encode,
+    "bitshuffle.decode": _bitshuffle_decode,
+}
+
+
+def test_every_pipeline_stage_has_a_compile_case():
+    assert set(CASES) == set(dispatch.PIPELINE_STAGES)
+
+
+@pytest.mark.parametrize("kernel", dispatch.PIPELINE_STAGES)
+def test_kernel_compiles_for_v5e(kernel, spec, on_chip):
+    CASES[kernel](spec, on_chip)

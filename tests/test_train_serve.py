@@ -7,7 +7,8 @@ import pytest
 from repro import configs
 from repro.models import model as M
 from repro.optim import adamw
-from repro.serve.engine import ServeConfig, generate, prefill, make_serve_step
+from repro.serve.engine import (ServeConfig, generate, load_params,
+                                prefill, make_serve_step)
 from repro.train.train_step import TrainConfig, make_train_step, loss_fn, \
     _microbatched_grads
 
@@ -97,3 +98,36 @@ class TestServe:
         b = np.asarray(generate(params, cfg, prompt, 8,
                                 ServeConfig(s_max=128, compressed_kv=True)))
         assert (a == b).mean() > 0.6          # greedy mostly agrees
+
+
+class TestEntrypoints:
+    def test_serving_params_stored_at_compute_dtype(self):
+        cfg = configs.reduced("qwen2.5-3b")
+        scfg = ServeConfig(compute_dtype=jnp.bfloat16)
+        got = load_params(jax.random.PRNGKey(3), cfg, scfg)
+        want = M.init_params(jax.random.PRNGKey(3), cfg)
+        for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+            assert g.dtype == jnp.bfloat16
+            np.testing.assert_array_equal(
+                np.asarray(g), np.asarray(w.astype(jnp.bfloat16)))
+
+    def test_train_launcher_returns_step_losses(self, monkeypatch):
+        from repro.launch import env as launch_env
+        from repro.launch import train as launch_train
+        # keep this process's compiles out of the checkout's cache
+        monkeypatch.setattr(launch_env, "enable_compile_cache",
+                            lambda: launch_env.CHECKOUT_CACHE)
+        losses = launch_train.main(
+            ["--arch", "qwen2.5-3b", "--reduced", "--layers", "2",
+             "--steps", "2", "--batch", "2", "--seq", "16"])
+        assert len(losses) == 2 and np.all(np.isfinite(losses))
+
+    def test_local_mesh_follows_device_count(self):
+        from repro.launch.mesh import make_local_mesh
+        n = jax.device_count()
+        if n % 2:
+            with pytest.raises(ValueError, match="do not split"):
+                make_local_mesh()
+        else:
+            assert dict(make_local_mesh().shape) == {
+                "pod": 2, "data": n // 2, "model": 1}
