@@ -25,6 +25,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.debug import spans
+
 from .container import (ChecksumError, Container, Header, check_container,
                         make_header, stamp_checksum, verify_container)
 
@@ -58,9 +60,11 @@ class Codec:
         crc32 (``checksum``) in the header."""
         if c.header.param("packed"):
             return c
-        # repro-lint: allow[host-sync] pack() IS the device->storage boundary
-        payload = {k: np.asarray(jax.device_get(v))
-                   for k, v in c.payload.items()}
+        payload = {}
+        for k, v in c.payload.items():
+            spans.count_sync(v)
+            # repro-lint: allow[host-sync] pack() IS the storage boundary
+            payload[k] = np.asarray(jax.device_get(v))
         return stamp_checksum(
             Container(c.header.with_params(packed=True), payload))
 

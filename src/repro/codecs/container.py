@@ -22,6 +22,8 @@ from typing import Any, Dict, Mapping, Tuple
 import jax
 import numpy as np
 
+from repro.debug import spans
+
 CONTAINER_FORMAT = 1
 
 
@@ -130,8 +132,9 @@ class Container:
     # -- conveniences -------------------------------------------------------
     @property
     def nbytes(self) -> int:
-        # repro-lint: allow[host-sync] size accounting is a host-side query
-        return sum(np.asarray(jax.device_get(v)).nbytes
+        # each array's own size: no pull of device data to the host
+        return sum(int(v.nbytes if hasattr(v, "nbytes")
+                       else np.asarray(v).nbytes)
                    for v in self.payload.values())
 
     def replace(self, header: Header = None, payload=None) -> "Container":
@@ -156,6 +159,7 @@ def payload_crc32(payload: Mapping[str, Any]) -> int:
     that flips data bytes — also fails verification."""
     crc = 0
     for k in sorted(payload):
+        spans.count_sync(payload[k])
         # repro-lint: allow[host-sync] checksumming is a host/storage op
         arr = np.ascontiguousarray(np.asarray(jax.device_get(payload[k])))
         meta = f"{k}:{arr.dtype.str}:{arr.shape};".encode()
@@ -166,8 +170,9 @@ def payload_crc32(payload: Mapping[str, Any]) -> int:
 def stamp_checksum(c: "Container") -> "Container":
     """Record the payload crc32 in the header (storage-form containers;
     every `pack` implementation ends with this)."""
-    return c.replace(header=c.header.with_params(
-        checksum=payload_crc32(c.payload)))
+    with spans.span("codec.pack.crc32"):
+        return c.replace(header=c.header.with_params(
+            checksum=payload_crc32(c.payload)))
 
 
 def verify_container(c: "Container") -> bool:
@@ -237,8 +242,11 @@ def concat_containers(parts, axis: int, field_axes: Mapping[str, Any]
 
 def to_arrays(c: Container) -> Tuple[Dict[str, Any], Dict[str, np.ndarray]]:
     """(header-json, {field: numpy array}) — the npz/storage form."""
-    # repro-lint: allow[host-sync] to_arrays() is the npz/storage boundary
-    arrays = {k: np.asarray(jax.device_get(v)) for k, v in c.payload.items()}
+    arrays = {}
+    for k, v in c.payload.items():
+        spans.count_sync(v)
+        # repro-lint: allow[host-sync] to_arrays() is the storage boundary
+        arrays[k] = np.asarray(jax.device_get(v))
     return c.header.to_json(), arrays
 
 

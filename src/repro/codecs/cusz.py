@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import compressor as CZ
+from repro.debug import spans
 
 from .base import Codec, register
 from .container import Container, stamp_checksum
@@ -93,6 +94,7 @@ class CuszCodec(Codec):
         (the blob would decode lossily beyond the bound)."""
         if c.header.param("packed"):
             return True                       # pack() is post-validation
+        spans.count_sync(c.payload["n_outliers"])
         # repro-lint: allow[host-sync] one scalar readback per validity check
         n_out = int(jax.device_get(c.payload["n_outliers"]))
         return n_out <= int(c.payload["out_idx"].shape[0])

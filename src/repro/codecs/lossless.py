@@ -14,6 +14,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.debug import spans
+
 from .base import Codec, register
 from .container import Container, stamp_checksum
 
@@ -35,6 +37,7 @@ class LosslessCodec(Codec):
     def pack(self, c: Container) -> Container:
         if c.header.param("packed"):
             return c
+        spans.count_sync(c.payload["data"])
         # repro-lint: allow[host-sync] pack() IS the device->storage boundary
         arr = np.asarray(jax.device_get(c.payload["data"]))
         if arr.dtype.kind not in "biufc":          # e.g. ml_dtypes bfloat16

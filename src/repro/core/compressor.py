@@ -45,6 +45,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.debug import spans
 from repro.kernels import dispatch
 
 from . import dualquant as dq
@@ -102,15 +103,19 @@ def _eb_stats(data: jax.Array) -> jax.Array:
     """min, max, max|d| as ONE fused reduction -> one [3] device array.
     One dispatch + one device_get per compress call (the previous form
     issued two separate blocking reductions)."""
-    f = data.astype(jnp.float32)
-    return jnp.stack([jnp.min(f), jnp.max(f), jnp.max(jnp.abs(f))])
+    with jax.named_scope("stage.eb_stats"):
+        f = data.astype(jnp.float32)
+        return jnp.stack([jnp.min(f), jnp.max(f), jnp.max(jnp.abs(f))])
 
 
 def resolve_eb(cfg: CompressorConfig, data) -> float:
-    # repro-lint: allow[host-sync] single fused 3-stat reduction; the eb
-    # must be a host float (jit cache key) before compression starts
-    dmin, dmax, amax = (float(v) for v in
-                        np.asarray(jax.device_get(_eb_stats(data))))
+    with spans.span("codec.resolve_eb"):
+        stats = _eb_stats(data)
+        spans.count_sync(stats)
+        # repro-lint: allow[host-sync] single fused 3-stat reduction; the
+        # eb must be a host float (jit cache key) before compression starts
+        dmin, dmax, amax = (float(v) for v in
+                            np.asarray(jax.device_get(stats)))
     if cfg.eb_mode == "abs":
         eb = float(cfg.eb)
     else:
@@ -162,7 +167,8 @@ def staged_compress(data: jax.Array, cfg: CompressorConfig
     """Generic staged compress.  Returns (payload dict, resolved abs eb)."""
     eb = resolve_eb(cfg, data)
     pp = dispatch.pipeline_policy(cfg.kernel_impl)
-    return _staged_compress_impl(data, cfg, eb, pp), eb
+    with spans.span("codec.dispatch"):
+        return _staged_compress_impl(data, cfg, eb, pp), eb
 
 
 def staged_decompress(payload: dict, cfg: CompressorConfig, eb: float,
@@ -171,8 +177,9 @@ def staged_decompress(payload: dict, cfg: CompressorConfig, eb: float,
     enc = stages.get_encoder(cfg.encoder)
     static_meta, aux = enc.decode_meta(payload, cfg)
     pp = dispatch.pipeline_policy(cfg.kernel_impl)
-    return _staged_decompress_impl(payload, aux, cfg, eb, tuple(shape),
-                                   static_meta, pp)
+    with spans.span("codec.dispatch"):
+        return _staged_decompress_impl(payload, aux, cfg, eb, tuple(shape),
+                                       static_meta, pp)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -202,6 +209,7 @@ class StagedPipeline:
 
     # -- storage boundary (host) -------------------------------------------
     def pack(self, payload: dict) -> dict:
+        spans.count_sync(payload)
         # repro-lint: allow[host-sync] pack() is the storage boundary
         host = jax.device_get(payload)
         pkeys = set(self.predictor.payload_keys)
@@ -246,7 +254,8 @@ def compress(data: jax.Array, cfg: CompressorConfig) -> Tuple[CompressedBlob, fl
             f"layout; encoder {cfg.encoder!r} needs staged_compress()")
     eb = resolve_eb(cfg, data)
     pp = dispatch.pipeline_policy(cfg.kernel_impl)
-    return _compress_impl(data, cfg, eb, pp), eb
+    with spans.span("codec.dispatch"):
+        return _compress_impl(data, cfg, eb, pp), eb
 
 
 @partial(jax.jit, static_argnames=("cfg", "eb", "shape", "max_len_static",
@@ -269,8 +278,9 @@ def decompress(blob: CompressedBlob, cfg: CompressorConfig, eb: float,
     static_meta, table = enc.decode_meta(
         {"max_len": blob.max_len, "lengths": blob.lengths}, cfg)
     pp = dispatch.pipeline_policy(cfg.kernel_impl)
-    return _decompress_impl(blob, table, cfg, eb, tuple(shape),
-                            static_meta[0], pp)
+    with spans.span("codec.dispatch"):
+        return _decompress_impl(blob, table, cfg, eb, tuple(shape),
+                                static_meta[0], pp)
 
 
 # ---------------------------------------------------------------------------
@@ -281,9 +291,11 @@ HEADER_BYTES = 64
 
 
 def compressed_bytes(blob: CompressedBlob, nbins: int) -> int:
+    spans.count_sync(blob.bits_used)
     # repro-lint: allow[host-sync] ratio reporting is a host-side metric
     bits = np.asarray(jax.device_get(blob.bits_used), dtype=np.int64)
     stream = int(np.sum((bits + 31) // 32) * 4)
+    spans.count_sync(blob.n_outliers)
     n_out = int(jax.device_get(blob.n_outliers))  # repro-lint: allow[host-sync] ratio reporting
 
     outliers = n_out * 8                       # (idx, delta) int32 pairs
@@ -317,12 +329,15 @@ def roundtrip(data: jax.Array, cfg: CompressorConfig):
 # ---------------------------------------------------------------------------
 
 def pack_blob(blob: CompressedBlob) -> dict:
-    # repro-lint: allow[host-sync] pack_blob() is the storage boundary
-    b = jax.device_get(blob)
+    with spans.span("codec.pack.d2h"):
+        spans.count_sync(blob)
+        # repro-lint: allow[host-sync] pack_blob() is the storage boundary
+        b = jax.device_get(blob)
     payload = {f: v for f, v in zip(CompressedBlob._fields, b)
                if v is not None}
-    d = stages.get_encoder("huffman").pack_payload(payload)
-    d.update(stages._pack_outliers(payload))
+    with spans.span("codec.pack.words"):
+        d = stages.get_encoder("huffman").pack_payload(payload)
+        d.update(stages._pack_outliers(payload))
     if payload.get("anchor") is not None:
         d["anchor"] = np.asarray(payload["anchor"], np.int32)
     return d
@@ -333,11 +348,14 @@ def packed_nbytes(d: dict) -> int:
 
 
 def unpack_blob(d: dict) -> CompressedBlob:
-    enc = stages.get_encoder("huffman").unpack_payload(d, None, None)
-    out = stages._unpack_outliers(d)
+    with spans.span("codec.unpack.words"):
+        enc = stages.get_encoder("huffman").unpack_payload(d, None, None)
+        out = stages._unpack_outliers(d)
     payload = {**enc, **out}
     if d.get("anchor") is not None:
         payload["anchor"] = np.asarray(d["anchor"], np.int32)
-    return CompressedBlob(**{
-        f: (jnp.asarray(payload[f]) if payload.get(f) is not None else None)
-        for f in CompressedBlob._fields})
+    with spans.span("codec.unpack.h2d"):
+        return CompressedBlob(**{
+            f: (jnp.asarray(payload[f]) if payload.get(f) is not None
+                else None)
+            for f in CompressedBlob._fields})

@@ -48,6 +48,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.debug import spans
+
 MAXLEN = 32          # hard cap on codeword bitlength (u32 stream words)
 LUT_BITS = 16        # use table decoder when max bitlength <= this
 SUBCHUNK = 128       # default gap-array subchunk (symbols per decode unit):
@@ -458,14 +460,15 @@ def _length_bounds(cb: Codebook) -> Tuple[jax.Array, jax.Array]:
 def build_decode_table(lengths: jax.Array, max_len_static: int) -> DecodeTable:
     """Codebook + decode tables from stored bitlengths (one jit per
     (nbins, bucketed max_len) — NOT per field)."""
-    cb = canonical_codebook(lengths)
-    thresh, lmask = _length_bounds(cb)
-    if max_len_static <= LUT_BITS:
-        lut_sym, lut_len = _build_lut(cb, max(1, max_len_static))
-    else:
-        lut_sym = jnp.zeros((1,), jnp.int32)
-        lut_len = jnp.zeros((1,), jnp.int32)
-    return DecodeTable(cb, lut_sym, lut_len, thresh, lmask)
+    with jax.named_scope("stage.decode_table"):
+        cb = canonical_codebook(lengths)
+        thresh, lmask = _length_bounds(cb)
+        if max_len_static <= LUT_BITS:
+            lut_sym, lut_len = _build_lut(cb, max(1, max_len_static))
+        else:
+            lut_sym = jnp.zeros((1,), jnp.int32)
+            lut_len = jnp.zeros((1,), jnp.int32)
+        return DecodeTable(cb, lut_sym, lut_len, thresh, lmask)
 
 
 # identity-keyed LRU: repeated decodes of the same stored codebook (serve
@@ -484,7 +487,9 @@ def decode_table(lengths: jax.Array, max_len_static: int) -> DecodeTable:
     hit = _DECODE_TABLE_CACHE.get(key)
     if hit is not None and hit[0] is lengths:
         _DECODE_TABLE_CACHE.move_to_end(key)
+        spans.count("decode_table.hits")
         return hit[1]
+    spans.count("decode_table.builds")
     tbl = build_decode_table(lengths, int(max_len_static))
     _DECODE_TABLE_CACHE[key] = (lengths, tbl)
     while len(_DECODE_TABLE_CACHE) > _DECODE_TABLE_CACHE_SIZE:

@@ -40,6 +40,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.debug import spans
 from repro.kernels import dispatch
 from repro.kernels.deflate import ops as deflate_ops
 from repro.kernels.encode import ops as encode_ops
@@ -218,6 +219,7 @@ def outlier_capacity(n: int, cfg) -> int:
 
 
 def _outlier_valid(payload: Dict[str, np.ndarray]) -> bool:
+    spans.count_sync(payload["n_outliers"])
     # repro-lint: allow[host-sync] one scalar readback per validity check
     n_out = int(jax.device_get(payload["n_outliers"]))
     return n_out <= int(payload["out_idx"].shape[0])
@@ -261,28 +263,34 @@ class LorenzoPredictor(Predictor):
 
     def predict(self, data, cfg, eb, pp):
         ndim, block, pshape, n, cap = shape_meta(data.shape, cfg)
-        xb = dq.block_split(dq.pad_to_blocks(data, block), block)
+        with jax.named_scope("stage.blocks"):
+            xb = dq.block_split(dq.pad_to_blocks(data, block), block)
         # fused PREQUANT + ℓ-delta + POSTQUANT: one blocked kernel call
-        codes, delta = lorenzo_ops.dualquant_blocks(
-            xb, eb, cfg.nbins, **pp.for_kernel("lorenzo.dualquant")
-            .as_kwargs())
+        with jax.named_scope("stage.dualquant"):
+            codes, delta = lorenzo_ops.dualquant_blocks(
+                xb, eb, cfg.nbins, **pp.for_kernel("lorenzo.dualquant")
+                .as_kwargs())
         # code 0 <=> outlier (in-cap codes are >= 1), so the fused outputs
         # feed outlier extraction directly — no recomputed in_cap tree
-        oidx, oval, n_out = dq.extract_outliers(
-            delta.reshape(-1), (codes != 0).reshape(-1), cap)
+        with jax.named_scope("stage.outliers"):
+            oidx, oval, n_out = dq.extract_outliers(
+                delta.reshape(-1), (codes != 0).reshape(-1), cap)
         return codes, {"out_idx": oidx, "out_val": oval, "n_outliers": n_out}
 
     def reconstruct(self, codes_flat, payload, cfg, eb, shape, pp):
         ndim, block, pshape, n, cap = shape_meta(shape, cfg)
-        delta = dq.codes_to_delta(codes_flat[:n], cfg.nbins)
-        delta = dq.scatter_outliers(delta, payload["out_idx"],
-                                    payload["out_val"])
+        with jax.named_scope("stage.scatter"):
+            delta = dq.codes_to_delta(codes_flat[:n], cfg.nbins)
+            delta = dq.scatter_outliers(delta, payload["out_idx"],
+                                        payload["out_val"])
         nb = tuple(p // b for p, b in zip(pshape, block))
-        delta = delta.reshape(nb + tuple(block))
-        recon = lorenzo_ops.reverse_blocks(
-            delta, eb, **pp.for_kernel("lorenzo.reverse").as_kwargs())
-        full = dq.block_merge(recon, block)
-        return full[tuple(slice(0, s) for s in shape)]
+        with jax.named_scope("stage.reverse"):
+            recon = lorenzo_ops.reverse_blocks(
+                delta.reshape(nb + tuple(block)), eb,
+                **pp.for_kernel("lorenzo.reverse").as_kwargs())
+        with jax.named_scope("stage.blocks"):
+            full = dq.block_merge(recon, block)
+            return full[tuple(slice(0, s) for s in shape)]
 
     def header_params(self, shape, cfg):
         return {"block": tuple(cfg.block_for(len(shape))),
@@ -316,43 +324,49 @@ class HuffmanEncoder(Encoder):
                     "gap_bits", "gap_syms")
 
     def encode(self, codes, cfg, pp):
-        hist = hist_ops.histogram(codes, cfg.nbins,
-                                  **pp.for_kernel("histogram").as_kwargs())
-        lengths = hf.codeword_lengths(hist)
-        cb = hf.canonical_codebook(lengths)
-        cw, bw = encode_ops.encode(codes, cb,
-                                   **pp.for_kernel("encode").as_kwargs())
-        words, bits, gap_bits, gap_syms = deflate_ops.deflate(
-            cw, bw, cfg.chunk_size, cfg.sub_size,
-            **pp.for_kernel("deflate").as_kwargs())
-        nc = words.shape[0]
-        n_sym = codes.size
-        n_valid = jnp.minimum(
-            jnp.full((nc,), cfg.chunk_size, jnp.int32),
-            jnp.maximum(n_sym - jnp.arange(nc, dtype=jnp.int32)
-                        * cfg.chunk_size, 0))
+        with jax.named_scope("stage.histogram"):
+            hist = hist_ops.histogram(
+                codes, cfg.nbins, **pp.for_kernel("histogram").as_kwargs())
+        with jax.named_scope("stage.codebook"):
+            lengths = hf.codeword_lengths(hist)
+            cb = hf.canonical_codebook(lengths)
+        with jax.named_scope("stage.encode"):
+            cw, bw = encode_ops.encode(codes, cb,
+                                       **pp.for_kernel("encode").as_kwargs())
+        with jax.named_scope("stage.deflate"):
+            words, bits, gap_bits, gap_syms = deflate_ops.deflate(
+                cw, bw, cfg.chunk_size, cfg.sub_size,
+                **pp.for_kernel("deflate").as_kwargs())
+            nc = words.shape[0]
+            n_valid = jnp.minimum(
+                jnp.full((nc,), cfg.chunk_size, jnp.int32),
+                jnp.maximum(codes.size - jnp.arange(nc, dtype=jnp.int32)
+                            * cfg.chunk_size, 0))
         return {"words": words, "bits_used": bits, "n_valid": n_valid,
                 "lengths": lengths, "max_len": cb.max_len,
                 "gap_bits": gap_bits, "gap_syms": gap_syms}
 
     def decode_meta(self, payload, cfg):
-        # repro-lint: allow[host-sync] max_len picks the LUT-vs-bitscan
-        # decode variant, a static jit arg; one readback per decode
-        max_len = int(jax.device_get(payload["max_len"]))
-        # bucket the static max length (8/12/16/32) so decode compiles
-        # once per bucket, not once per field's exact max codeword length
-        ml_b = hf.bucket_max_len(max(1, max_len))
-        # decode tables built OUTSIDE the jitted decode, cached per book
-        table = hf.decode_table(payload["lengths"], ml_b)
+        with spans.span("codec.decode_meta"):
+            spans.count_sync(payload["max_len"])
+            # repro-lint: allow[host-sync] max_len picks the LUT-vs-bitscan
+            # decode variant, a static jit arg; one readback per decode
+            max_len = int(jax.device_get(payload["max_len"]))
+            # bucket the static max length (8/12/16/32) so decode compiles
+            # once per bucket, not once per field's exact max codeword
+            # length
+            ml_b = hf.bucket_max_len(max(1, max_len))
+            # decode tables built OUTSIDE the jitted decode, cached per book
+            table = hf.decode_table(payload["lengths"], ml_b)
         return (ml_b,), table
 
     def decode(self, payload, aux, static_meta, cfg, pp):
         (ml_b,) = static_meta
-        gaps = payload.get("gap_bits")
-        return inflate_ops.inflate(
-            payload["words"], payload["bits_used"], payload["n_valid"],
-            aux, ml_b, gaps=gaps,
-            **pp.for_kernel("inflate").as_kwargs()).reshape(-1)
+        with jax.named_scope("stage.inflate"):
+            return inflate_ops.inflate(
+                payload["words"], payload["bits_used"], payload["n_valid"],
+                aux, ml_b, gaps=payload.get("gap_bits"),
+                **pp.for_kernel("inflate").as_kwargs()).reshape(-1)
 
     def pack_payload(self, payload):
         bits = np.asarray(payload["bits_used"], dtype=np.int64)

@@ -20,7 +20,10 @@ static analysis cannot — actual compiles and actual syncs):
   first `repro` source frame on the stack and raises `HostSyncError`
   at scope exit for any site not in `allowed` (the statically waived
   ``allow[host-sync]`` spans, see ``tools.lint.waived_spans``).  This
-  is the CPU-meaningful complement to the transfer guard.  Limitation:
+  is the CPU-meaningful complement to the transfer guard.  A call on
+  host data alone (no `jax.Array` leaf) blocks on nothing and is not
+  counted: the same rule as the ``host_syncs`` counter
+  (`spans.count_sync`).  Limitation:
   ``float()``/``bool()`` on an array sync inside C code and cannot be
   intercepted here — the static layer covers those.
 """
@@ -35,6 +38,8 @@ import traceback
 from typing import Dict, Iterator, List, Optional, Sequence, Tuple
 
 import jax
+
+from .spans import holds_device_data
 
 _COMPILE_RE = re.compile(
     r"Finished XLA compilation of (?:jit\()?([\w<>\-.]+)\)? in")
@@ -182,7 +187,9 @@ def host_sync_guard(allowed: Optional[AllowedSites] = None,
     real_get, real_block = jax.device_get, jax.block_until_ready
     here = __file__
 
-    def _check(kind: str) -> None:
+    def _check(kind: str, x) -> None:
+        if not holds_device_data(x):
+            return
         site = _attribute_frame(here)
         if site is None:
             return
@@ -195,11 +202,11 @@ def host_sync_guard(allowed: Optional[AllowedSites] = None,
         log.violations.append(f"{path}:{line} {kind}")
 
     def guarded_get(x):
-        _check("jax.device_get")
+        _check("jax.device_get", x)
         return real_get(x)
 
     def guarded_block(x):
-        _check("jax.block_until_ready")
+        _check("jax.block_until_ready", x)
         return real_block(x)
 
     jax.device_get, jax.block_until_ready = guarded_get, guarded_block

@@ -1,0 +1,296 @@
+"""The codec path's spans, counters and stage scopes (repro.debug.spans).
+
+The recorder on its own (nesting, the ring's bound, counter events),
+then what one compress and one decompress call at test size record:
+the documented ``codec.*`` spans in order, the ``host_syncs`` counter
+against the syncs the host-sync guard attributes, the decode-table
+cache's hits and builds, and the ``stage.*`` scopes in the lowered
+pipeline.
+"""
+from __future__ import annotations
+
+import collections
+import re
+import sys
+import threading
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from repro import codecs
+from repro.core import compressor as CZ
+from repro.debug import host_sync_guard, spans
+from repro.kernels import dispatch
+
+
+def _since(t0: float):
+    """(spans, counts) the recorder holds that ended after `t0`."""
+    snap = spans.snapshot()
+    return ([s for s in snap["spans"] if s.t1 >= t0],
+            [c for c in snap["counts"] if c.t >= t0])
+
+
+# -- the recorder --------------------------------------------------------------
+
+def test_spans_nest_with_parent_and_root_id():
+    t0 = time.perf_counter()
+    with spans.span("t.outer"):
+        with spans.span("t.inner"):
+            spans.count("t.events", 3)
+        with spans.span("t.second"):
+            pass
+    with spans.span("t.next"):
+        pass
+    got, counts = _since(t0)
+    by = {s.name: s for s in got}
+    # a span is recorded when it ends: children before their parent
+    assert [s.name for s in got] == ["t.inner", "t.second", "t.outer",
+                                     "t.next"]
+    assert by["t.outer"].parent is None
+    assert by["t.inner"].parent == by["t.second"].parent == "t.outer"
+    assert by["t.inner"].root_id == by["t.outer"].root_id
+    assert by["t.next"].root_id != by["t.outer"].root_id
+    assert by["t.outer"].t0 <= by["t.inner"].t0 <= by["t.inner"].t1 \
+        <= by["t.second"].t0 <= by["t.outer"].t1
+    (c,) = counts
+    assert (c.name, c.n, c.root_id) == ("t.events", 3, by["t.outer"].root_id)
+
+
+def test_a_span_is_recorded_when_its_block_raises():
+    t0 = time.perf_counter()
+    with pytest.raises(ValueError):
+        with spans.span("t.raises"):
+            raise ValueError("x")
+    with spans.span("t.after"):
+        pass
+    got, _ = _since(t0)
+    assert [s.name for s in got] == ["t.raises", "t.after"]
+    assert got[1].parent is None          # the stack was unwound
+
+
+def test_the_ring_is_bounded_and_counts_what_it_dropped(monkeypatch):
+    monkeypatch.setattr(spans, "_ring", collections.deque(maxlen=4))
+    monkeypatch.setattr(spans, "_dropped", 0)
+    for i in range(3):
+        spans.count(f"t.c{i}")
+    assert spans.snapshot()["dropped"] == 0
+    for i in range(3, 7):
+        with spans.span(f"t.s{i}"):
+            pass
+    snap = spans.snapshot()
+    assert snap["dropped"] == 3           # the oldest three went first
+    assert [c.name for c in snap["counts"]] == []
+    assert [s.name for s in snap["spans"]] == ["t.s3", "t.s4", "t.s5",
+                                               "t.s6"]
+
+
+def test_threads_lose_no_record_and_no_drop(monkeypatch):
+    """Many threads appending at once: every record is either kept or
+    counted as dropped, and each thread's spans nest on its own stack."""
+    monkeypatch.setattr(spans, "_ring", collections.deque(maxlen=1000))
+    monkeypatch.setattr(spans, "_dropped", 0)
+    n_threads, n_each = 16, 400
+    errors = []
+
+    def work(i):
+        try:
+            for _ in range(n_each // 2):
+                with spans.span(f"t.thread{i}"):
+                    spans.count("t.n")
+        except Exception as e:  # noqa: BLE001 - reported below
+            errors.append(e)
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        threads = [threading.Thread(target=work, args=(i,))
+                   for i in range(n_threads)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+    finally:
+        sys.setswitchinterval(old)
+    assert not any(t.is_alive() for t in threads) and errors == []
+    snap = spans.snapshot()
+    kept = len(snap["spans"]) + len(snap["counts"])
+    assert kept == 1000
+    assert kept + snap["dropped"] == n_threads * n_each
+    # each thread's spans are top-level on its own stack, and a count in
+    # one carries its root id
+    assert all(s.parent is None for s in snap["spans"])
+    assert all(c.root_id is not None for c in snap["counts"])
+    assert len({s.root_id for s in snap["spans"]}) == len(snap["spans"])
+
+
+def test_count_sync_counts_device_data_only():
+    t0 = time.perf_counter()
+    spans.count_sync(np.ones(3))
+    spans.count_sync({"a": np.ones(3), "b": 1})
+    spans.count_sync({"a": np.ones(3), "b": jnp.ones(3)})
+    _, counts = _since(t0)
+    assert [(c.name, c.n) for c in counts] == [("host_syncs", 1)]
+
+
+# -- one call of each kind at test size -----------------------------------------
+
+@pytest.fixture(scope="module")
+def codec_and_field():
+    rng = np.random.default_rng(0)
+    x = jnp.asarray(rng.standard_normal((16, 24, 24)).cumsum(0),
+                    jnp.float32)
+    codec = codecs.get("cusz", eb=1e-3, eb_mode="valrel")
+    packed = codecs.to_arrays(codec.pack(codec.encode(x)))   # warm
+    codecs.decode(codec.unpack(codecs.from_arrays(*packed)))
+    return codec, x, packed
+
+
+def _compress(codec, x):
+    """What the benchmark's compress call does."""
+    c = codec.encode(x)
+    jax.block_until_ready(c.payload)
+    return codecs.to_arrays(codec.pack(c))
+
+
+def _decompress(codec, packed):
+    """What the benchmark's decompress call does."""
+    c = codec.unpack(codecs.from_arrays(*packed))
+    return codecs.decode(c).block_until_ready()
+
+
+def test_a_compress_call_records_its_spans_in_order(codec_and_field):
+    codec, x, _ = codec_and_field
+    t0 = time.perf_counter()
+    with spans.span("t.call"):
+        _compress(codec, x)
+    got, counts = _since(t0)
+    assert [s.name for s in got] == [
+        "codec.resolve_eb", "codec.dispatch", "codec.pack.d2h",
+        "codec.pack.words", "codec.pack.crc32", "t.call"]
+    root = got[-1].root_id
+    assert all(s.parent == "t.call" and s.root_id == root
+               for s in got[:-1])
+    assert all(a.t1 <= b.t0 for a, b in zip(got[:-2], got[1:-1]))
+    assert [(c.name, c.root_id) for c in counts] == [("host_syncs", root)] * 2
+
+
+def test_a_decompress_call_records_its_spans_in_order(codec_and_field):
+    codec, _, packed = codec_and_field
+    t0 = time.perf_counter()
+    with spans.span("t.call"):
+        _decompress(codec, packed)
+    got, counts = _since(t0)
+    assert [s.name for s in got] == [
+        "codec.unpack.words", "codec.unpack.h2d", "codec.decode_meta",
+        "codec.dispatch", "t.call"]
+    assert all(s.parent == "t.call" for s in got[:-1])
+    assert sorted(c.name for c in counts) == ["decode_table.builds",
+                                              "host_syncs"]
+
+
+@pytest.mark.parametrize("op,want", [("compress", 2), ("decompress", 1)])
+def test_host_syncs_equal_the_syncs_the_guard_attributes(
+        codec_and_field, host_sync_sanitizer, op, want):
+    codec, x, packed = codec_and_field
+    t0 = time.perf_counter()
+    with host_sync_sanitizer() as log:
+        if op == "compress":
+            _compress(codec, x)
+        else:
+            _decompress(codec, packed)
+    _, counts = _since(t0)
+    n = sum(c.n for c in counts if c.name == "host_syncs")
+    assert log.violations == []
+    assert n == len(log.allowed_hits) == want, log.allowed_hits
+
+
+def test_the_guard_ignores_reads_of_host_data():
+    with host_sync_guard({}) as log:       # empty allowlist: a sync trips
+        codecs.container.payload_crc32({"a": np.arange(4)})
+    assert log.violations == []
+
+
+def test_repeated_decodes_of_one_device_container_hit_the_table_cache(
+        codec_and_field):
+    codec, _, packed = codec_and_field
+    c = codec.unpack(codecs.from_arrays(*packed))
+    t0 = time.perf_counter()
+    for _ in range(3):
+        codecs.decode(c).block_until_ready()
+    _, counts = _since(t0)
+    assert [c.name for c in counts if c.name.startswith("decode_table")] \
+        == ["decode_table.builds", "decode_table.hits", "decode_table.hits"]
+    # the benchmark unpacks afresh every call: a new codebook array, so a
+    # build every time
+    t0 = time.perf_counter()
+    for _ in range(2):
+        _decompress(codec, packed)
+    _, counts = _since(t0)
+    assert [c.name for c in counts if c.name.startswith("decode_table")] \
+        == ["decode_table.builds"] * 2
+
+
+def test_container_nbytes_reads_no_device_data(codec_and_field):
+    codec, x, _ = codec_and_field
+    c = codec.encode(x)
+    with host_sync_guard({}) as log:       # any sync would be a violation
+        n = c.nbytes
+    assert log.violations == []
+    assert n == sum(np.asarray(v).nbytes for v in c.payload.values())
+
+
+# -- stage scopes in the lowered pipeline ---------------------------------------
+
+TRIVIAL = {"parameter", "constant", "get-tuple-element", "tuple",
+           "broadcast"}
+
+
+def _pipeline_ops():
+    """(opcode, op_name) of every instruction of the compress pipeline's
+    body in the lowered HLO, with its metadata."""
+    from jax._src.lib import xla_client as xc
+    cfg = CZ.CompressorConfig(eb=1e-3, eb_mode="abs")
+    pp = dispatch.pipeline_policy(cfg.kernel_impl)
+    x = jax.ShapeDtypeStruct((16, 32, 32), jnp.float32)
+    module = CZ._compress_impl.lower(x, cfg, 1e-3, pp) \
+        .compiler_ir("hlo").get_hlo_module()
+    opts = xc._xla.HloPrintOptions()
+    opts.print_metadata = True
+    text = module.to_string(opts)
+    body = next(c for c in re.split(r"\n(?=\S)", text)
+                if c.startswith("%_staged_compress_impl"))
+    ops = []
+    for line in body.splitlines()[1:]:
+        m = re.search(r"=\s*(?:\S+|\(.*?\))\s+([\w-]+)\(", line)
+        if m:
+            name = re.search(r'op_name="([^"]*)"', line)
+            ops.append((m.group(1), name.group(1) if name else ""))
+    return ops
+
+
+def test_every_pipeline_op_carries_a_stage_scope():
+    ops = _pipeline_ops()
+    assert len(ops) > 50
+    bare = [(o, n) for o, n in ops if o not in TRIVIAL
+            and not re.match(r"stage\.\w+/", n)]
+    assert bare == []
+    scopes = {re.match(r"stage\.\w+", n).group(0) for o, n in ops
+              if o not in TRIVIAL}
+    assert scopes == {"stage.blocks", "stage.dualquant", "stage.outliers",
+                      "stage.histogram", "stage.codebook", "stage.encode",
+                      "stage.deflate"}
+
+
+def test_the_nonzero_compaction_is_in_the_outlier_scope():
+    ops = _pipeline_ops()
+    # jnp.nonzero(size=...) is a cumsum of the mask, a scatter-add
+    # (bincount) of it and a cumsum of that; the value gather follows
+    outl = [(o, n) for o, n in ops if n.startswith("stage.outliers/")]
+    assert ("scatter", "stage.outliers/scatter-add") in outl
+    assert ("call", "stage.outliers/jit(cumsum)") in outl
+    assert ("gather", "stage.outliers/gather") in outl
+    assert not any(n.startswith("stage.outliers/") for o, n in ops
+                   if o == "custom-call")
