@@ -96,8 +96,9 @@ def _stage_timers(f: jax.Array, cfg: C.CompressorConfig, eb: float,
                    zip(dq.padded_shape(f.shape, block), block))
         dblk = jnp.zeros(nb + tuple(block), jnp.int32)
         timers["lorenzo.dualquant"] = lambda impl: (
-            lambda x: lorenzo_ops.dualquant_blocks(x, eb, cfg.nbins,
-                                                   impl=impl), (xb,))
+            lambda x: lorenzo_ops.dualquant_blocks(
+                x, eb, cfg.nbins, stages.outlier_capacity(x.size, cfg),
+                impl=impl), (xb,))
         timers["lorenzo.reverse"] = lambda impl: (
             lambda d: lorenzo_ops.reverse_blocks(d, eb, impl=impl), (dblk,))
 

@@ -96,6 +96,9 @@ class CompressedBlob(NamedTuple):
     #   before each boundary
     # interp-predictor anchor grid (None for the lorenzo predictor):
     anchor: Optional[jax.Array] = None     # [n_anchor] int32
+    # Pallas lorenzo kernel: int32 [tiles holding an outlier, tiles];
+    # counted by pack_blob, never stored, not passed to decompress
+    outlier_tiles: Optional[jax.Array] = None
 
 
 @jax.jit
@@ -174,6 +177,8 @@ def staged_compress(data: jax.Array, cfg: CompressorConfig
 def staged_decompress(payload: dict, cfg: CompressorConfig, eb: float,
                       shape: Tuple[int, ...]) -> jax.Array:
     """Generic staged decompress of a (device-form) payload dict."""
+    # a fresh payload and an unpacked one share one decompress program
+    payload = {k: v for k, v in payload.items() if k != "outlier_tiles"}
     enc = stages.get_encoder(cfg.encoder)
     static_meta, aux = enc.decode_meta(payload, cfg)
     pp = dispatch.pipeline_policy(cfg.kernel_impl)
@@ -278,6 +283,8 @@ def decompress(blob: CompressedBlob, cfg: CompressorConfig, eb: float,
     static_meta, table = enc.decode_meta(
         {"max_len": blob.max_len, "lengths": blob.lengths}, cfg)
     pp = dispatch.pipeline_policy(cfg.kernel_impl)
+    # a fresh blob and an unpacked one share one decompress program
+    blob = blob._replace(outlier_tiles=None)
     with spans.span("codec.dispatch"):
         return _decompress_impl(blob, table, cfg, eb, tuple(shape),
                                 static_meta[0], pp)

@@ -226,7 +226,13 @@ def _outlier_valid(payload: Dict[str, np.ndarray]) -> bool:
 
 
 def _pack_outliers(payload: Dict[str, np.ndarray]) -> Dict[str, np.ndarray]:
-    """Trim the fixed-capacity outlier store to its used prefix."""
+    """Trim the fixed-capacity outlier store to its used prefix.  Counts
+    the outlier tiles the predictor reported (`outliers.tiles_hit` of
+    `outliers.tiles`) from this host copy; they are not stored."""
+    if payload.get("outlier_tiles") is not None:
+        hit, tiles = (int(v) for v in payload["outlier_tiles"])
+        spans.count("outliers.tiles_hit", hit)
+        spans.count("outliers.tiles", tiles)
     n_out = int(payload["n_outliers"])
     return {
         "out_idx": np.asarray(payload["out_idx"][:n_out], np.int32),
@@ -256,7 +262,7 @@ def _unpack_outliers(packed: Dict[str, np.ndarray]
 class LorenzoPredictor(Predictor):
     name = "lorenzo"
     kernels = ("lorenzo.dualquant", "lorenzo.reverse")
-    payload_keys = ("out_idx", "out_val", "n_outliers")
+    payload_keys = ("out_idx", "out_val", "n_outliers", "outlier_tiles")
 
     def n_codes(self, shape, cfg) -> int:
         return shape_meta(shape, cfg)[3]
@@ -265,17 +271,16 @@ class LorenzoPredictor(Predictor):
         ndim, block, pshape, n, cap = shape_meta(data.shape, cfg)
         with jax.named_scope("stage.blocks"):
             xb = dq.block_split(dq.pad_to_blocks(data, block), block)
-        # fused PREQUANT + ℓ-delta + POSTQUANT: one blocked kernel call
+        # fused PREQUANT + ℓ-delta + POSTQUANT and the outlier store: one
+        # blocked kernel call (the deltas never leave the kernel)
         with jax.named_scope("stage.dualquant"):
-            codes, delta = lorenzo_ops.dualquant_blocks(
-                xb, eb, cfg.nbins, **pp.for_kernel("lorenzo.dualquant")
+            codes, oidx, oval, n_out, tiles = lorenzo_ops.dualquant_blocks(
+                xb, eb, cfg.nbins, cap, **pp.for_kernel("lorenzo.dualquant")
                 .as_kwargs())
-        # code 0 <=> outlier (in-cap codes are >= 1), so the fused outputs
-        # feed outlier extraction directly — no recomputed in_cap tree
-        with jax.named_scope("stage.outliers"):
-            oidx, oval, n_out = dq.extract_outliers(
-                delta.reshape(-1), (codes != 0).reshape(-1), cap)
-        return codes, {"out_idx": oidx, "out_val": oval, "n_outliers": n_out}
+        payload = {"out_idx": oidx, "out_val": oval, "n_outliers": n_out}
+        if tiles is not None:
+            payload["outlier_tiles"] = tiles
+        return codes, payload
 
     def reconstruct(self, codes_flat, payload, cfg, eb, shape, pp):
         ndim, block, pshape, n, cap = shape_meta(shape, cfg)

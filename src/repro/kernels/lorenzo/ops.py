@@ -13,6 +13,7 @@ impl='pallas' -> Pallas kernel (interpret=True on CPU for validation,
 """
 from __future__ import annotations
 
+import math
 from functools import partial
 from typing import Optional
 
@@ -25,20 +26,30 @@ DUALQUANT = dispatch.register("lorenzo.dualquant", impls=("jax", "pallas"))
 REVERSE = dispatch.register("lorenzo.reverse", impls=("jax", "pallas"))
 
 
-@partial(jax.jit, static_argnames=("eb", "nbins", "impl", "interpret"))
-def _dualquant_jit(xb, eb: float, nbins: int, impl: str, interpret: bool):
-    if impl == "pallas":
-        return kernel.dualquant_blocks_pallas(xb, eb, nbins,
+@partial(jax.jit, static_argnames=("eb", "nbins", "capacity", "impl",
+                                   "interpret"))
+def _dualquant_jit(xb, eb: float, nbins: int, capacity: int, impl: str,
+                   interpret: bool):
+    # the kernel lays a block out in 128-lane rows; a block of another
+    # size (no default or TPU block is one) runs the reference
+    if impl == "pallas" and math.prod(xb.shape[xb.ndim // 2:]) % 128 == 0:
+        return kernel.dualquant_blocks_pallas(xb, eb, nbins, capacity,
                                               interpret=interpret)
-    return ref.dualquant_blocks_ref(xb, eb, nbins)
+    return ref.dualquant_blocks_ref(xb, eb, nbins, capacity)
 
 
-def dualquant_blocks(xb, eb: float, nbins: int, impl: Optional[str] = None,
+def dualquant_blocks(xb, eb: float, nbins: int, capacity: int,
+                     impl: Optional[str] = None,
                      interpret: Optional[bool] = None):
-    """Fused PREQUANT + ℓ-delta + POSTQUANT on blocked input.
-    Returns (codes, delta), both int32 shaped like xb."""
+    """Fused PREQUANT + ℓ-delta + POSTQUANT on blocked input, with the
+    outlier store of `core.dualquant.extract_outliers`.
+
+    Returns (codes int32 shaped like xb, out_idx [capacity], out_val
+    [capacity], n_outliers, outlier_tiles): `outlier_tiles` is int32
+    [tiles holding an outlier, tiles] of the Pallas kernel's walk over
+    4096-value tiles, and None from the reference, which walks none."""
     r = dispatch.resolve(DUALQUANT, impl, interpret)
-    return _dualquant_jit(xb, eb, nbins, r.impl, r.interpret)
+    return _dualquant_jit(xb, eb, nbins, capacity, r.impl, r.interpret)
 
 
 @partial(jax.jit, static_argnames=("eb", "impl", "interpret"))

@@ -177,6 +177,38 @@ def test_a_compress_call_records_its_spans_in_order(codec_and_field):
     assert [(c.name, c.root_id) for c in counts] == [("host_syncs", root)] * 2
 
 
+def test_a_compress_call_counts_its_outlier_tiles_without_a_sync(
+        codec_and_field, host_sync_sanitizer):
+    from repro.core import dualquant as dq
+    _, x, _ = codec_and_field
+    # at an absolute 1e-3 the blocks' corners (predicted from 0) are
+    # outliers; the Pallas dual-quant kernel is the one that walks tiles
+    eb = 1e-3
+    codec = codecs.get("cusz", eb=eb, eb_mode="abs")
+    with dispatch.kernel_policy(
+            overrides={"lorenzo.dualquant": "pallas-interpret"}):
+        _compress(codec, x)                               # warm
+        t0 = time.perf_counter()
+        with host_sync_sanitizer() as log:
+            _compress(codec, x)
+    got, counts = _since(t0)
+    n = {c.name: c.n for c in counts}
+    # 16x24x24 in 8x8x8 blocks: 18 blocks, 9216 values, 3 tiles of 4096
+    xb = dq.block_split(dq.pad_to_blocks(x, (8, 8, 8)), (8, 8, 8))
+    _, in_cap = dq.postquant_codes(
+        dq.lorenzo_delta(dq.prequant(xb, eb), axes=(3, 4, 5)), 1024)
+    out = np.pad(~np.asarray(in_cap).reshape(-1), (0, 3 * 4096 - 9216))
+    hit = int(out.reshape(3, 4096).any(axis=1).sum())
+    assert (n["outliers.tiles_hit"], n["outliers.tiles"]) == (hit, 3)
+    assert hit > 0
+    # counted from the host copy pack_blob already fetched
+    assert [c.name for c in counts].count("host_syncs") == 2
+    assert len(log.allowed_hits) == 2 and log.violations == []
+    words = next(s for s in got if s.name == "codec.pack.words")
+    assert all(words.t0 <= c.t <= words.t1 for c in counts
+               if c.name.startswith("outliers."))
+
+
 def test_a_decompress_call_records_its_spans_in_order(codec_and_field):
     codec, _, packed = codec_and_field
     t0 = time.perf_counter()
@@ -248,9 +280,10 @@ TRIVIAL = {"parameter", "constant", "get-tuple-element", "tuple",
            "broadcast"}
 
 
-def _pipeline_ops():
+def _pipeline_ops(body: str = "_staged_compress_impl"):
     """(opcode, op_name) of every instruction of the compress pipeline's
-    body in the lowered HLO, with its metadata."""
+    body (or of the jitted function `body` it calls) in the lowered HLO,
+    with its metadata."""
     from jax._src.lib import xla_client as xc
     cfg = CZ.CompressorConfig(eb=1e-3, eb_mode="abs")
     pp = dispatch.pipeline_policy(cfg.kernel_impl)
@@ -261,7 +294,7 @@ def _pipeline_ops():
     opts.print_metadata = True
     text = module.to_string(opts)
     body = next(c for c in re.split(r"\n(?=\S)", text)
-                if c.startswith("%_staged_compress_impl"))
+                if c.startswith(f"%{body}"))
     ops = []
     for line in body.splitlines()[1:]:
         m = re.search(r"=\s*(?:\S+|\(.*?\))\s+([\w-]+)\(", line)
@@ -279,13 +312,15 @@ def test_every_pipeline_op_carries_a_stage_scope():
     assert bare == []
     scopes = {re.match(r"stage\.\w+", n).group(0) for o, n in ops
               if o not in TRIVIAL}
-    assert scopes == {"stage.blocks", "stage.dualquant", "stage.outliers",
-                      "stage.histogram", "stage.codebook", "stage.encode",
-                      "stage.deflate"}
+    # the outlier store is an output of the fused dual-quant op
+    assert scopes == {"stage.blocks", "stage.dualquant", "stage.histogram",
+                      "stage.codebook", "stage.encode", "stage.deflate"}
 
 
 def test_the_nonzero_compaction_is_in_the_outlier_scope():
-    ops = _pipeline_ops()
+    # the reference dual-quant op (the CPU's), whose body the pipeline
+    # calls under stage.dualquant
+    ops = _pipeline_ops("_dualquant_jit")
     # jnp.nonzero(size=...) is a cumsum of the mask, a scatter-add
     # (bincount) of it and a cumsum of that; the value gather follows
     outl = [(o, n) for o, n in ops if n.startswith("stage.outliers/")]
@@ -294,3 +329,4 @@ def test_the_nonzero_compaction_is_in_the_outlier_scope():
     assert ("gather", "stage.outliers/gather") in outl
     assert not any(n.startswith("stage.outliers/") for o, n in ops
                    if o == "custom-call")
+    assert ("call", "stage.dualquant/jit(_dualquant_jit)") in _pipeline_ops()

@@ -163,6 +163,15 @@ ODD_CASES = [
 ]
 
 
+def _delta(out, nbins):
+    """The blocked deltas, rebuilt from the fused op's codes and outlier
+    store as the decoder does."""
+    codes, idx, val = out[:3]
+    d = dq.scatter_outliers(dq.codes_to_delta(codes.reshape(-1), nbins),
+                            idx, val)
+    return d.reshape(codes.shape)
+
+
 def _field(shape, seed=0):
     rng = np.random.default_rng(seed)
     return jnp.asarray(np.cumsum(rng.standard_normal(shape), axis=-1)
@@ -175,11 +184,13 @@ class TestParity:
         table = dq.TPU_BLOCKS if tpu else dq.DEFAULT_BLOCKS
         block = table[len(shape)]
         xb = dq.block_split(dq.pad_to_blocks(_field(shape), block), block)
-        ck, dk = lorenzo_ops.dualquant_blocks(xb, 1e-3, 1024,
-                                              impl="pallas-interpret")
-        cr, dr = lorenzo_ops.dualquant_blocks(xb, 1e-3, 1024, impl="jax")
-        np.testing.assert_array_equal(np.asarray(ck), np.asarray(cr))
-        np.testing.assert_array_equal(np.asarray(dk), np.asarray(dr))
+        outk = lorenzo_ops.dualquant_blocks(xb, 1e-3, 1024, xb.size,
+                                            impl="pallas-interpret")
+        outr = lorenzo_ops.dualquant_blocks(xb, 1e-3, 1024, xb.size,
+                                            impl="jax")
+        for k, r in zip(outk[:4], outr[:4]):
+            np.testing.assert_array_equal(np.asarray(k), np.asarray(r))
+        dk, dr = _delta(outk, 1024), _delta(outr, 1024)
         rk = lorenzo_ops.reverse_blocks(dk, 1e-3, impl="pallas-interpret")
         rr = lorenzo_ops.reverse_blocks(dr, 1e-3, impl="jax")
         np.testing.assert_array_equal(np.asarray(rk), np.asarray(rr))
@@ -216,7 +227,9 @@ class TestParity:
         x = _field((37, 53), seed=9)
         block = dq.DEFAULT_BLOCKS[2]
         xb = dq.block_split(dq.pad_to_blocks(x, block), block)
-        cf, df = lorenzo_ops.dualquant_blocks(xb, 1e-3, 1024, impl="jax")
+        out = lorenzo_ops.dualquant_blocks(xb, 1e-3, 1024, xb.size,
+                                           impl="jax")
+        cf, df = out[0], _delta(out, 1024)
         du = dq.blocked_delta(x, 1e-3, block)
         cu, _ = dq.postquant_codes(du, 1024)
         np.testing.assert_array_equal(np.asarray(df), np.asarray(du))
@@ -240,7 +253,11 @@ class TestForcedPallasRoundtrip:
         blob_r, eb_r = C.compress(f, base)
         blob_p, eb_p = C.compress(f, forced)
         assert eb_r == eb_p
-        for a, b in zip(blob_r, blob_p):
+        # the Pallas kernel also reports the outlier tiles it walked (a
+        # counter pack_blob records, not part of the result)
+        assert blob_r.outlier_tiles is None
+        assert blob_p.outlier_tiles is not None
+        for a, b in zip(blob_r, blob_p._replace(outlier_tiles=None)):
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
         rec_r = C.decompress(blob_r, base, eb_r, shape)
         rec_p = C.decompress(blob_p, forced, eb_p, shape)
@@ -257,6 +274,20 @@ class TestForcedPallasRoundtrip:
             cfg, kernel_impl="jax"))
         np.testing.assert_array_equal(np.asarray(recon),
                                       np.asarray(recon_ref))
+
+
+def test_a_fresh_and_an_unpacked_blob_share_one_decompress_program():
+    f = _field((40, 40), seed=3)
+    cfg = C.CompressorConfig(eb=1e-3, eb_mode="valrel", chunk_size=512)
+    with dispatch.kernel_policy(
+            overrides={"lorenzo.dualquant": "pallas-interpret"}):
+        blob, eb = C.compress(f, cfg)
+    assert blob.outlier_tiles is not None       # the kernel's counter
+    fresh = C.decompress(blob, cfg, eb, f.shape)
+    n = C._decompress_impl._cache_size()
+    back = C.decompress(C.unpack_blob(C.pack_blob(blob)), cfg, eb, f.shape)
+    assert C._decompress_impl._cache_size() == n
+    np.testing.assert_array_equal(np.asarray(fresh), np.asarray(back))
 
 
 # ---------------------------------------------------------------------------

@@ -35,10 +35,13 @@ class TestLorenzoKernel:
     @pytest.mark.parametrize("eb", [1e-2, 1e-3])
     def test_dualquant_matches_ref(self, shape, block, eb):
         xb = _blocked(shape, block, seed=hash((shape, block)) % 2**31)
-        ck, dk = lorenzo_ops.dualquant_blocks(xb, eb, 1024, impl="pallas")
-        cr, dr = lorenzo_ops.dualquant_blocks(xb, eb, 1024, impl="jax")
-        np.testing.assert_array_equal(np.asarray(ck), np.asarray(cr))
-        np.testing.assert_array_equal(np.asarray(dk), np.asarray(dr))
+        cap = max(16, xb.size // 10)
+        outk = lorenzo_ops.dualquant_blocks(xb, eb, 1024, cap, impl="pallas")
+        outr = lorenzo_ops.dualquant_blocks(xb, eb, 1024, cap, impl="jax")
+        for k, r in zip(outk[:4], outr[:4]):
+            np.testing.assert_array_equal(np.asarray(k), np.asarray(r))
+        assert outr[4] is None
+        np.testing.assert_array_equal(np.asarray(outk[4]), _tiles(outr[0]))
 
     @pytest.mark.parametrize("shape,block", BLOCK_CASES)
     def test_reverse_matches_ref(self, shape, block):
@@ -54,10 +57,119 @@ class TestLorenzoKernel:
         """Kernel forward + kernel reverse obeys the paper's bound."""
         eb = 1e-3
         xb = _blocked((64, 128), (16, 16), seed=3, scale=0.1)
-        codes, delta = lorenzo_ops.dualquant_blocks(xb, eb, nbins, impl="pallas")
-        recon = lorenzo_ops.reverse_blocks(delta, eb, impl="pallas")
+        codes, idx, val, n_out, _ = lorenzo_ops.dualquant_blocks(
+            xb, eb, nbins, xb.size, impl="pallas")
+        delta = dq.scatter_outliers(
+            dq.codes_to_delta(codes.reshape(-1), nbins), idx, val)
+        recon = lorenzo_ops.reverse_blocks(delta.reshape(xb.shape), eb,
+                                           impl="pallas")
         err = np.abs(np.asarray(recon) - np.asarray(xb))
         assert err.max() <= eb * (1 + 1e-4) + 1e-7
+
+
+def _tiles(codes):
+    """[tiles holding an outlier, tiles] over the runs of 4096 values of
+    a blocked field at a lane-aligned block width, in flat order (an
+    outlier has code 0)."""
+    out = np.asarray(codes).reshape(-1) == 0
+    t = -(-out.size // 4096)
+    out = np.pad(out, (0, t * 4096 - out.size))
+    return [int(out.reshape(t, 4096).any(axis=1).sum()), t]
+
+
+def _spiky(n, spikes, seed=0):
+    """A smooth 1-D walk (no outlier at eb 0.5) with a jump of 5000 at
+    each index in `spikes` (an outlier there and one just after)."""
+    rng = np.random.default_rng(seed)
+    x = np.cumsum(rng.integers(-3, 4, n)).astype(np.float32)
+    x[np.asarray(spikes, np.int64)] += 5000.0
+    return x
+
+
+def _alternating(n):
+    """Every Lorenzo delta an outlier at eb 0.5."""
+    return np.where(np.arange(n) % 2, 1000.0, -1000.0).astype(np.float32)
+
+
+OUTLIER_CASES = {
+    # name: (field, block, capacity or None for 10% of the blocked n)
+    "none": (lambda: _spiky(5000, []), (256,), None),
+    "all_within_capacity": (lambda: _alternating(4096), (256,), 4096),
+    "overflow": (lambda: _alternating(4096), (256,), 409),
+    "n_not_a_multiple_of_the_tile": (lambda: _spiky(3000, [5, 700, 2999]),
+                                     (256,), None),
+    "hundreds_in_one_tile": (lambda: _spiky(6000, range(100, 1900, 6)),
+                             (256,), None),
+    "shorter_than_one_tile": (lambda: _spiky(40, [3, 17]), (256,), None),
+    "steps_and_a_partial_last_step": (
+        lambda: _spiky(300_001, list(range(0, 300_001, 997)) + [131_071,
+                                                                 131_072]),
+        (256,), None),
+    "overflow_inside_a_later_step": (
+        lambda: _spiky(300_001, range(0, 300_001, 1500)), (256,), 250),
+    "blocks_8x8x8_fixture_size": (
+        lambda: np.cumsum(np.random.default_rng(5).standard_normal(
+            (16, 64, 64)), -1).astype(np.float32) * 40, (8, 8, 8), None),
+    # a tile of 8 outliers is picked one by one, one of 9 is compacted
+    "eight_then_nine_in_a_tile": (
+        lambda: _spiky(12288, [100, 200, 300, 400,
+                               4200, 4300, 4400, 4500, 8191]),
+        (256,), None),
+    # the lane-aligned TPU blocks: a block row spans many 4096-value tiles
+    "tpu_block_4096_hundreds_in_one_tile": (
+        lambda: _spiky(20000, range(4100, 5000, 6)), (4096,), None),
+    "tpu_blocks_8x16x128_two_steps": (
+        lambda: np.cumsum(np.random.default_rng(6).standard_normal(
+            (24, 32, 256)), -1).astype(np.float32) * 40, (8, 16, 128), None),
+}
+
+
+class TestOutlierStore:
+    """The fused kernel's outlier store against `extract_outliers` (the
+    reference), bit for bit: indices, deltas, the fill past the count
+    and the true count; and the kernel's tile counts against NumPy."""
+
+    @pytest.mark.parametrize("case", sorted(OUTLIER_CASES))
+    def test_matches_ref(self, case):
+        make, block, cap = OUTLIER_CASES[case]
+        x = jnp.asarray(make())
+        xb = dq.block_split(dq.pad_to_blocks(x, block), block)
+        cap = cap or max(16, xb.size // 10)
+        outk = lorenzo_ops.dualquant_blocks(xb, 0.5, 1024, cap,
+                                            impl="pallas-interpret")
+        outr = lorenzo_ops.dualquant_blocks(xb, 0.5, 1024, cap, impl="jax")
+        for name, k, r in zip(("codes", "idx", "val", "n"), outk, outr):
+            np.testing.assert_array_equal(np.asarray(k), np.asarray(r),
+                                          err_msg=name)
+        np.testing.assert_array_equal(np.asarray(outk[4]), _tiles(outr[0]))
+        idx, val, n_out = (np.asarray(v) for v in outr[1:4])
+        used = min(int(n_out), cap)
+        assert (idx[used:] == xb.size).all() and (val[used:] == 0).all()
+        assert (np.diff(idx[:used]) > 0).all()
+
+    @pytest.mark.parametrize("block", [(256,), (4096,)])
+    def test_counts_of_a_known_field(self, block):
+        # spikes at 10 and 5000 (tiles 0 and 1 of 3 at any lane-aligned
+        # block width), each an outlier and its successor
+        xb = dq.block_split(jnp.asarray(_spiky(12288, [10, 5000])), block)
+        codes, idx, val, n_out, tiles = lorenzo_ops.dualquant_blocks(
+            xb, 0.5, 1024, 1228, impl="pallas-interpret")
+        assert int(n_out) == 4
+        np.testing.assert_array_equal(np.asarray(tiles), [2, 3])
+        np.testing.assert_array_equal(np.asarray(idx[:5]),
+                                      [10, 11, 5000, 5001, 12288])
+        assert lorenzo_ops.dualquant_blocks(xb, 0.5, 1024, 1228,
+                                            impl="jax")[4] is None
+
+    def test_a_block_off_the_lanes_runs_the_reference(self):
+        # 8x8 blocks (64 values) cannot be laid out in 128-lane rows
+        xb = _blocked((40, 40), (8, 8), seed=4)
+        outk = lorenzo_ops.dualquant_blocks(xb, 1e-3, 1024, 160,
+                                            impl="pallas-interpret")
+        outr = lorenzo_ops.dualquant_blocks(xb, 1e-3, 1024, 160, impl="jax")
+        for k, r in zip(outk[:4], outr[:4]):
+            np.testing.assert_array_equal(np.asarray(k), np.asarray(r))
+        assert outk[4] is None
 
 
 class TestHistogramKernel:

@@ -13,6 +13,7 @@ The topology is described inside a module fixture (never at import), and
 every compile runs with the persistent compilation cache off: a compile
 for a described chip can be written to the cache but never read back.
 """
+import math
 import os
 
 import jax
@@ -38,8 +39,8 @@ HACC = (280_953_867,)
 CHUNK, SUB, FZ_CHUNK = 4096, 128, 512
 
 
-def _blocked(shape):
-    block = dq.DEFAULT_BLOCKS[len(shape)]
+def _blocked(shape, table=dq.DEFAULT_BLOCKS):
+    block = table[len(shape)]
     nb = tuple(-(-s // b) for s, b in zip(shape, block))
     return nb + block
 
@@ -122,10 +123,15 @@ def _codebook_shapes(on_chip):
 
 # one compile per (kernel, width); ids name the kernel as dispatch does
 def _lorenzo_dualquant(spec, on_chip):
-    for shape in (HURRICANE, HACC):
+    # with the outlier store at the codec's default capacity (10% of n),
+    # also at the lane-aligned blocks the checkpoint codecs use
+    for shape, table in ((HURRICANE, dq.DEFAULT_BLOCKS),
+                         (HACC, dq.DEFAULT_BLOCKS),
+                         (HURRICANE, dq.TPU_BLOCKS), (HACC, dq.TPU_BLOCKS)):
+        xb = _blocked(shape, table)
         _compiles(lambda x: lorenzo_k.dualquant_blocks_pallas(
-            x, 1e-3, NBINS, interpret=False),
-            spec(_blocked(shape), jnp.float32))
+            x, 1e-3, NBINS, math.prod(xb) // 10, interpret=False),
+            spec(xb, jnp.float32))
 
 
 def _lorenzo_reverse(spec, on_chip):
