@@ -171,7 +171,9 @@ def staged_compress(data: jax.Array, cfg: CompressorConfig
     eb = resolve_eb(cfg, data)
     pp = dispatch.pipeline_policy(cfg.kernel_impl)
     with spans.span("codec.dispatch"):
-        return _staged_compress_impl(data, cfg, eb, pp), eb
+        payload = _staged_compress_impl(data, cfg, eb, pp)
+    stages.get_predictor(cfg.predictor).record_call(data.shape, cfg, pp)
+    return payload, eb
 
 
 def staged_decompress(payload: dict, cfg: CompressorConfig, eb: float,
@@ -260,7 +262,9 @@ def compress(data: jax.Array, cfg: CompressorConfig) -> Tuple[CompressedBlob, fl
     eb = resolve_eb(cfg, data)
     pp = dispatch.pipeline_policy(cfg.kernel_impl)
     with spans.span("codec.dispatch"):
-        return _compress_impl(data, cfg, eb, pp), eb
+        blob = _compress_impl(data, cfg, eb, pp)
+    stages.get_predictor(cfg.predictor).record_call(data.shape, cfg, pp)
+    return blob, eb
 
 
 @partial(jax.jit, static_argnames=("cfg", "eb", "shape", "max_len_static",

@@ -23,6 +23,13 @@ whole multi-level loop unrolls inside one jit trace — per-level shapes
 change, which rules out `lax.scan`, but level count is log2(max dim).
 The residual stream order (level-major, then row-major in working-axis-
 moved layout) is likewise static and shared by predict/reconstruct.
+
+Predict runs under the named scopes `stage.prequant`, `stage.interp`
+(the lifting steps and the quant codes) and `stage.outliers`;
+reconstruct under `stage.scatter` and `stage.interleave` (the inverse
+lifting and the dequantisation).  Each compress call counts
+`interp.tiles` (`repro.debug.spans`): the grid steps of the Pallas
+predict kernel, from the static plan.
 """
 from __future__ import annotations
 
@@ -33,6 +40,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.debug import spans
+from repro.kernels.interp import kernel as interp_kernel
 from repro.kernels.interp import ops as interp_ops
 
 from . import dualquant as dq
@@ -68,6 +77,13 @@ def interp_plan(shape: Tuple[int, ...]
     return tuple(steps), tuple(s)
 
 
+def predict_tiles(shape: Tuple[int, ...]) -> int:
+    """Grid steps the Pallas predict kernel runs over a field of `shape`:
+    one level of R rows takes ceil(R / row tile) steps."""
+    return sum(interp_kernel.grid_steps(int(np.prod(shp)) // shp[axis])
+               for axis, shp in interp_plan(shape)[0])
+
+
 def _n_residuals(shape: Tuple[int, ...]) -> Tuple[int, int]:
     steps, anchor_shape = interp_plan(shape)
     n_res = int(np.prod(shape)) - int(np.prod(anchor_shape))
@@ -100,39 +116,46 @@ class InterpPredictor(stages.Predictor):
 
     def predict(self, data, cfg, eb, pp):
         steps, anchor_shape = interp_plan(data.shape)
-        n_res, _ = _n_residuals(data.shape)
         kw = pp.for_kernel("interp.predict").as_kwargs()
-        x = dq.prequant(data, eb)
-        parts = []
-        for axis, _ in steps:
-            xm = jnp.moveaxis(x, axis, -1)
-            even, odd = xm[..., 0::2], xm[..., 1::2]
-            e2 = even.reshape(-1, even.shape[-1])
-            o2 = odd.reshape(-1, odd.shape[-1])
-            r2 = interp_ops.residual_rows(_pad_even(e2), o2, **kw)
-            parts.append(r2.reshape(-1))
-            x = jnp.moveaxis(even, -1, axis)
-        resid = (jnp.concatenate(parts) if parts
-                 else jnp.zeros((0,), jnp.int32))
-        if resid.shape[0] < self.n_codes(data.shape, cfg):
-            # degenerate all-ones shape: emit one in-cap dummy symbol so
-            # the encoder never sees an empty stream
-            resid = jnp.zeros((1,), jnp.int32)
-        codes, in_cap = dq.postquant_codes(resid, cfg.nbins)
+        with jax.named_scope("stage.prequant"):
+            x = dq.prequant(data, eb)
+        with jax.named_scope("stage.interp"):
+            parts = []
+            for axis, _ in steps:
+                xm = jnp.moveaxis(x, axis, -1)
+                even, odd = xm[..., 0::2], xm[..., 1::2]
+                e2 = even.reshape(-1, even.shape[-1])
+                o2 = odd.reshape(-1, odd.shape[-1])
+                r2 = interp_ops.residual_rows(_pad_even(e2), o2, **kw)
+                parts.append(r2.reshape(-1))
+                x = jnp.moveaxis(even, -1, axis)
+            resid = (jnp.concatenate(parts) if parts
+                     else jnp.zeros((0,), jnp.int32))
+            if resid.shape[0] < self.n_codes(data.shape, cfg):
+                # degenerate all-ones shape: emit one in-cap dummy symbol
+                # so the encoder never sees an empty stream
+                resid = jnp.zeros((1,), jnp.int32)
+            codes, in_cap = dq.postquant_codes(resid, cfg.nbins)
+            anchor = x.reshape(-1).astype(jnp.int32)
         cap = stages.outlier_capacity(int(np.prod(data.shape)), cfg)
-        oidx, oval, n_out = dq.extract_outliers(resid, in_cap.reshape(-1),
-                                                cap)
+        with jax.named_scope("stage.outliers"):
+            oidx, oval, n_out = dq.extract_outliers(
+                resid, in_cap.reshape(-1), cap)
         return codes, {"out_idx": oidx, "out_val": oval,
-                       "n_outliers": n_out,
-                       "anchor": x.reshape(-1).astype(jnp.int32)}
+                       "n_outliers": n_out, "anchor": anchor}
+
+    def record_call(self, shape, cfg, pp):
+        if pp.for_kernel("interp.predict").impl == "pallas":
+            spans.count("interp.tiles", predict_tiles(tuple(shape)))
 
     def reconstruct(self, codes_flat, payload, cfg, eb, shape, pp):
         steps, anchor_shape = interp_plan(shape)
         kw = pp.for_kernel("interp.reconstruct").as_kwargs()
         nc = self.n_codes(shape, cfg)
-        delta = dq.codes_to_delta(codes_flat[:nc], cfg.nbins)
-        delta = dq.scatter_outliers(delta, payload["out_idx"],
-                                    payload["out_val"])
+        with jax.named_scope("stage.scatter"):
+            delta = dq.codes_to_delta(codes_flat[:nc], cfg.nbins)
+            delta = dq.scatter_outliers(delta, payload["out_idx"],
+                                        payload["out_val"])
         # replay the plan to get each step's residual segment offset and
         # moved-layout odd shape (all static)
         segs = []
@@ -143,16 +166,17 @@ class InterpPredictor(stages.Predictor):
             odd_shape = moved[:-1] + (mo,)
             segs.append((axis, odd_shape, off))
             off += int(np.prod(odd_shape))
-        x = payload["anchor"].reshape(anchor_shape)
-        for axis, odd_shape, off in reversed(segs):
-            em = jnp.moveaxis(x, axis, -1)
-            e2 = em.reshape(-1, em.shape[-1])
-            mo = odd_shape[-1]
-            r2 = delta[off:off + int(np.prod(odd_shape))].reshape(-1, mo)
-            o2 = interp_ops.odd_rows(_pad_even(e2), r2, **kw)
-            om = o2.reshape(odd_shape)
-            x = jnp.moveaxis(_interleave(em, om), -1, axis)
-        return dq.dequant(x, eb)
+        with jax.named_scope("stage.interleave"):
+            x = payload["anchor"].reshape(anchor_shape)
+            for axis, odd_shape, off in reversed(segs):
+                em = jnp.moveaxis(x, axis, -1)
+                e2 = em.reshape(-1, em.shape[-1])
+                mo = odd_shape[-1]
+                r2 = delta[off:off + int(np.prod(odd_shape))].reshape(-1, mo)
+                o2 = interp_ops.odd_rows(_pad_even(e2), r2, **kw)
+                om = o2.reshape(odd_shape)
+                x = jnp.moveaxis(_interleave(em, om), -1, axis)
+            return dq.dequant(x, eb)
 
     def header_params(self, shape, cfg):
         return {"outlier_frac": float(cfg.outlier_frac)}

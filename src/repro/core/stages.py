@@ -94,6 +94,11 @@ class Predictor:
         Traced (inside jit)."""
         raise NotImplementedError
 
+    def record_call(self, shape: Tuple[int, ...], cfg,
+                    pp: dispatch.PipelinePolicy) -> None:
+        """Host-side counters of one compress call, from the static shape
+        and policy (no device read).  Called once per call, outside jit."""
+
     def header_params(self, shape: Tuple[int, ...], cfg) -> Dict[str, Any]:
         """Decode-side parameters a codec should record in its header."""
         return {}
@@ -227,13 +232,16 @@ def _outlier_valid(payload: Dict[str, np.ndarray]) -> bool:
 
 def _pack_outliers(payload: Dict[str, np.ndarray]) -> Dict[str, np.ndarray]:
     """Trim the fixed-capacity outlier store to its used prefix.  Counts
-    the outlier tiles the predictor reported (`outliers.tiles_hit` of
-    `outliers.tiles`) from this host copy; they are not stored."""
+    the values the predictor sent to the store (`outliers.values`, more
+    than it holds where it overflowed) and the outlier tiles it reported
+    (`outliers.tiles_hit` of `outliers.tiles`) from this host copy; the
+    tiles are not stored."""
     if payload.get("outlier_tiles") is not None:
         hit, tiles = (int(v) for v in payload["outlier_tiles"])
         spans.count("outliers.tiles_hit", hit)
         spans.count("outliers.tiles", tiles)
     n_out = int(payload["n_outliers"])
+    spans.count("outliers.values", n_out)
     return {
         "out_idx": np.asarray(payload["out_idx"][:n_out], np.int32),
         "out_val": np.asarray(payload["out_val"][:n_out], np.int32),

@@ -35,17 +35,27 @@ def _odd_kernel(mo, pe_ref, res_ref, out_ref):
     out_ref[...] = res_ref[...] + _predict_tile(pe_ref[...], mo)
 
 
+def row_tile(rows: int) -> int:
+    """Rows of one grid step for a level of `rows` rows."""
+    return min(_ROW_TILE, max(1, rows))
+
+
+def grid_steps(rows: int) -> int:
+    """Grid steps the kernel runs over a level of `rows` rows."""
+    return -(-rows // row_tile(rows))
+
+
 def _run(kern_fn, pe: jax.Array, other: jax.Array,
          interpret: bool) -> jax.Array:
     rows, mo = other.shape
     mp = pe.shape[1]
-    tile = min(_ROW_TILE, max(1, rows))
+    tile = row_tile(rows)
     pad = (-rows) % tile
     if pad:
         pe = jnp.concatenate([pe, jnp.zeros((pad, mp), pe.dtype)], axis=0)
         other = jnp.concatenate(
             [other, jnp.zeros((pad, mo), other.dtype)], axis=0)
-    grid = ((rows + pad) // tile,)
+    grid = (grid_steps(rows),)
     kern = functools.partial(kern_fn, mo)
     out = pl.pallas_call(
         kern,

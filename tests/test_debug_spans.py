@@ -22,6 +22,7 @@ import pytest
 
 from repro import codecs
 from repro.core import compressor as CZ
+from repro.core import huffman as hf
 from repro.debug import host_sync_guard, spans
 from repro.kernels import dispatch
 
@@ -174,7 +175,10 @@ def test_a_compress_call_records_its_spans_in_order(codec_and_field):
     assert all(s.parent == "t.call" and s.root_id == root
                for s in got[:-1])
     assert all(a.t1 <= b.t0 for a, b in zip(got[:-2], got[1:-1]))
-    assert [(c.name, c.root_id) for c in counts] == [("host_syncs", root)] * 2
+    # one sync in resolve_eb, one in pack_blob; the outlier store's
+    # values counted from pack_blob's host copy
+    assert [(c.name, c.root_id) for c in counts] == [
+        ("host_syncs", root), ("host_syncs", root), ("outliers.values", root)]
 
 
 def test_a_compress_call_counts_its_outlier_tiles_without_a_sync(
@@ -207,6 +211,42 @@ def test_a_compress_call_counts_its_outlier_tiles_without_a_sync(
     words = next(s for s in got if s.name == "codec.pack.words")
     assert all(words.t0 <= c.t <= words.t1 for c in counts
                if c.name.startswith("outliers."))
+
+
+def test_a_cusz_i_compress_call_counts_tiles_and_outliers_without_a_sync(
+        host_sync_sanitizer):
+    from repro.core import interp
+    rng = np.random.default_rng(0)
+    x = jnp.asarray(rng.standard_normal((10, 24, 40)).cumsum(0).cumsum(1),
+                    jnp.float32)
+    codec = codecs.get("cusz-i", eb=1e-4, eb_mode="valrel",
+                       kernel_impl="pallas-interpret")
+    _compress(codec, x)                                   # warm
+    t0 = time.perf_counter()
+    with spans.span("t.call"):
+        with host_sync_sanitizer() as log:
+            _, arrays = _compress(codec, x)
+    got, counts = _since(t0)
+    n = collections.Counter(c.name for c in counts)
+    assert n == {"host_syncs": 2, "interp.tiles": 1, "outliers.values": 1}
+    assert len(log.allowed_hits) == 2 and log.violations == []
+    by = {c.name: c.n for c in counts}
+    # 9 splits of 10x24x40 in 8-row tiles: 202 grid steps
+    assert by["interp.tiles"] == interp.predict_tiles((10, 24, 40)) == 202
+    assert interp.predict_tiles((100, 500, 500)) == 47936
+    assert by["outliers.values"] == arrays["out_idx"].shape[0] > 0
+    # tiles once the dispatch is enqueued, outliers inside the pack
+    dispatch_, words = (next(s for s in got if s.name == name)
+                        for name in ("codec.dispatch", "codec.pack.words"))
+    tiles_t = next(c.t for c in counts if c.name == "interp.tiles")
+    outl_t = next(c.t for c in counts if c.name == "outliers.values")
+    assert dispatch_.t1 <= tiles_t <= words.t0 <= outl_t <= words.t1
+    # the reference kernel runs no Pallas grid and counts no tiles
+    t0 = time.perf_counter()
+    _compress(codecs.get("cusz-i", eb=1e-4, eb_mode="valrel",
+                         kernel_impl="jax"), x)
+    _, counts = _since(t0)
+    assert "interp.tiles" not in {c.name for c in counts}
 
 
 def test_a_decompress_call_records_its_spans_in_order(codec_and_field):
@@ -280,21 +320,31 @@ TRIVIAL = {"parameter", "constant", "get-tuple-element", "tuple",
            "broadcast"}
 
 
-def _pipeline_ops(body: str = "_staged_compress_impl"):
+def _pipeline_ops(body: str = "_staged_compress_impl", cfg=None,
+                  shape=(16, 32, 32)):
     """(opcode, op_name) of every instruction of the compress pipeline's
-    body (or of the jitted function `body` it calls) in the lowered HLO,
-    with its metadata."""
+    body (or of the jitted function `body` it calls; `_decompress_impl`:
+    the decompress pipeline's) in the lowered HLO, with its metadata."""
     from jax._src.lib import xla_client as xc
-    cfg = CZ.CompressorConfig(eb=1e-3, eb_mode="abs")
+    cfg = cfg or CZ.CompressorConfig(eb=1e-3, eb_mode="abs")
     pp = dispatch.pipeline_policy(cfg.kernel_impl)
-    x = jax.ShapeDtypeStruct((16, 32, 32), jnp.float32)
-    module = CZ._compress_impl.lower(x, cfg, 1e-3, pp) \
-        .compiler_ir("hlo").get_hlo_module()
+    x = jax.ShapeDtypeStruct(shape, jnp.float32)
+    if body == "_decompress_impl":
+        blob = jax.eval_shape(lambda v: CZ._compress_impl(v, cfg, 1e-3, pp),
+                              x)._replace(outlier_tiles=None)
+        table = jax.eval_shape(lambda ln: hf.build_decode_table(ln, 16),
+                               jax.ShapeDtypeStruct((1024,), jnp.int32))
+        lowered = CZ._decompress_impl.lower(blob, table, cfg, 1e-3, shape,
+                                            16, pp)
+        body = "main"                      # the entry computation
+    else:
+        lowered = CZ._compress_impl.lower(x, cfg, 1e-3, pp)
+    module = lowered.compiler_ir("hlo").get_hlo_module()
     opts = xc._xla.HloPrintOptions()
     opts.print_metadata = True
     text = module.to_string(opts)
     body = next(c for c in re.split(r"\n(?=\S)", text)
-                if c.startswith(f"%{body}"))
+                if re.match(rf"(ENTRY )?%{body}\b", c))
     ops = []
     for line in body.splitlines()[1:]:
         m = re.search(r"=\s*(?:\S+|\(.*?\))\s+([\w-]+)\(", line)
@@ -330,3 +380,26 @@ def test_the_nonzero_compaction_is_in_the_outlier_scope():
     assert not any(n.startswith("stage.outliers/") for o, n in ops
                    if o == "custom-call")
     assert ("call", "stage.dualquant/jit(_dualquant_jit)") in _pipeline_ops()
+
+
+def test_the_interp_predictor_ops_carry_its_stage_scopes():
+    """cusz-i's compress and decompress pipelines: every op in a stage
+    scope, the predictor's named as documented in `core/interp.py`."""
+    cfg = CZ.CompressorConfig(eb=1e-3, eb_mode="abs", predictor="interp")
+    ops = _pipeline_ops(cfg=cfg, shape=(10, 24, 40))
+    bare = [(o, n) for o, n in ops if o not in TRIVIAL
+            and not re.match(r"stage\.\w+/", n)]
+    assert bare == []
+    scopes = {re.match(r"stage\.\w+", n).group(0) for o, n in ops
+              if o not in TRIVIAL}
+    assert scopes == {"stage.prequant", "stage.interp", "stage.outliers",
+                      "stage.histogram", "stage.codebook", "stage.encode",
+                      "stage.deflate"}
+    # the nonzero compaction of the outlier store is in its own scope
+    assert ("scatter", "stage.outliers/scatter-add") in ops
+    assert ("call", "stage.interp/jit(_residual_jit)") in ops
+    dec = _pipeline_ops("_decompress_impl", cfg=cfg, shape=(10, 24, 40))
+    names = {n for _, n in dec}
+    assert "jit(_decompress_impl)/stage.interleave/jit(_odd_jit)" in names
+    assert any(n.startswith("jit(_decompress_impl)/stage.scatter/")
+               for n in names)

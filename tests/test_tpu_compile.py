@@ -213,3 +213,31 @@ def test_every_pipeline_stage_has_a_compile_case():
 @pytest.mark.parametrize("kernel", dispatch.PIPELINE_STAGES)
 def test_kernel_compiles_for_v5e(kernel, spec, on_chip):
     CASES[kernel](spec, on_chip)
+
+
+def test_the_cusz_i_program_names_each_predict_step_for_its_roofline(
+        spec):
+    """Compiled for a v5e, the cusz-i compress program holds one Pallas
+    call per split of the plan, each named as the benchmark's
+    `interp_predict_roofline` reader finds it in a device trace (the bare
+    instruction name, `_residual_jit.<n>`), and that reader claims no
+    other kernel."""
+    import re
+    from bench import run
+    from repro.core import compressor as CZ
+    reader = run.load_reader(run.ROOT, "interp_predict_roofline")
+    pp = dispatch.PipelinePolicy(entries=tuple(
+        (k, dispatch.Resolved("pallas", False))
+        for k in dispatch.PIPELINE_STAGES))
+    cfg = CZ.CompressorConfig(eb=1e-3, eb_mode="abs", kernel_impl="pallas",
+                              predictor="interp")
+    shape = (20, 48, 80)
+    text = CZ._compress_impl.lower(spec(shape, jnp.float32), cfg, 1e-3,
+                                   pp).compile().as_text()
+    calls = [line.split("=")[0].strip().lstrip("%")
+             for line in text.splitlines() if "tpu_custom_call" in line]
+    mine = [c for c in calls if reader.EVENTS.search(c)]
+    assert len(mine) == len(IP.interp_plan(shape)[0]) == 12
+    assert all(re.match(r"_residual_jit\.\d+$", c) for c in mine)
+    assert sorted(set(calls) - set(mine)) == [
+        "_deflate_jit.1", "_encode_jit.1", "_histogram_jit.1"]
