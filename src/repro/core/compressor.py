@@ -185,8 +185,10 @@ def staged_decompress(payload: dict, cfg: CompressorConfig, eb: float,
     static_meta, aux = enc.decode_meta(payload, cfg)
     pp = dispatch.pipeline_policy(cfg.kernel_impl)
     with spans.span("codec.dispatch"):
-        return _staged_decompress_impl(payload, aux, cfg, eb, tuple(shape),
-                                       static_meta, pp)
+        out = _staged_decompress_impl(payload, aux, cfg, eb, tuple(shape),
+                                      static_meta, pp)
+    enc.record_call(payload, pp)
+    return out
 
 
 @dataclasses.dataclass(frozen=True)
@@ -290,8 +292,10 @@ def decompress(blob: CompressedBlob, cfg: CompressorConfig, eb: float,
     # a fresh blob and an unpacked one share one decompress program
     blob = blob._replace(outlier_tiles=None)
     with spans.span("codec.dispatch"):
-        return _decompress_impl(blob, table, cfg, eb, tuple(shape),
-                                static_meta[0], pp)
+        out = _decompress_impl(blob, table, cfg, eb, tuple(shape),
+                               static_meta[0], pp)
+    enc.record_call(blob._asdict(), pp)
+    return out
 
 
 # ---------------------------------------------------------------------------

@@ -45,6 +45,7 @@ from repro.kernels import dispatch
 from repro.kernels.deflate import ops as deflate_ops
 from repro.kernels.encode import ops as encode_ops
 from repro.kernels.histogram import ops as hist_ops
+from repro.kernels.inflate import kernel as inflate_kernel
 from repro.kernels.inflate import ops as inflate_ops
 from repro.kernels.lorenzo import ops as lorenzo_ops
 
@@ -152,6 +153,12 @@ class Encoder:
         """payload -> flat int32 codes (padded to the encoder's chunk
         granularity; callers slice `[:n_codes]`).  Traced (inside jit)."""
         raise NotImplementedError
+
+    def record_call(self, payload: Payload,
+                    pp: dispatch.PipelinePolicy) -> None:
+        """Host-side counters of one decompress call, from the payload's
+        static shapes and the policy (no device read).  Called once per
+        call, outside jit."""
 
     def pack_payload(self, payload: Dict[str, np.ndarray]
                      ) -> Dict[str, np.ndarray]:
@@ -380,6 +387,17 @@ class HuffmanEncoder(Encoder):
                 payload["words"], payload["bits_used"], payload["n_valid"],
                 aux, ml_b, gaps=payload.get("gap_bits"),
                 **pp.for_kernel("inflate").as_kwargs()).reshape(-1)
+
+    def record_call(self, payload, pp):
+        gaps = payload.get("gap_bits")
+        if gaps is None:
+            return
+        nc, chunk = payload["words"].shape
+        sub = chunk // gaps.shape[1]
+        r = inflate_ops.for_shape(pp.for_kernel("inflate"), chunk, sub)
+        if r.impl == "pallas":
+            spans.count("inflate.walk_steps",
+                        inflate_kernel.walk_steps(nc, chunk, sub))
 
     def pack_payload(self, payload):
         bits = np.asarray(payload["bits_used"], dtype=np.int64)

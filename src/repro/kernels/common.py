@@ -2,14 +2,14 @@
 MXU, zero-filled shifts, and the chunk tiling of the Huffman bitstream
 kernels.
 
-Mosaic refuses int32 matrix products, and has no gather with per-lane
-dynamic indices.  The kernels gather and pack 32-bit words by splitting
-them into four bytes and contracting **int8 operands into int32
-accumulators**.  Every product the kernels form sums, per output, either
-one nonzero byte (a one-hot gather) or bytes whose set bits are disjoint
-(bit-field packing).  Bytes travel as signed values in [-128, 128), so an
-accumulator holds the true byte sum minus a multiple of 256; its low 8
-bits are exact, and `from_bytes` keeps only those.
+Mosaic refuses int32 matrix products, and gathers with per-lane dynamic
+indices only within one vreg.  The kernels gather and pack 32-bit words
+by splitting them into four bytes and contracting **int8 operands into
+int32 accumulators**.  Every product the kernels form sums, per output,
+either one nonzero byte (a one-hot gather) or bytes whose set bits are
+disjoint (bit-field packing).  Bytes travel as signed values in
+[-128, 128), so an accumulator holds the true byte sum minus a multiple
+of 256; its low 8 bits are exact, and `from_bytes` keeps only those.
 
 Byte planes stay int32 until `dot_i8` casts each whole operand to int8,
 so no int8 value is sliced or stacked inside a kernel (int8 packs four
@@ -64,17 +64,16 @@ def shift(x: jax.Array, axis: int, k: int = 1) -> jax.Array:
                            axis=axis)
 
 
-def chunks_per_tile(chunk: int, sub: int = 128, min_rows: int = 32) -> int:
+def chunks_per_tile(chunk: int, min_rows: int = 32) -> int:
     """Chunks per grid step for kernels that lay each `chunk`-symbol
-    Huffman chunk out as `chunk // 128` rows of 128 lanes (and, for the
-    decoder, `chunk // sub` cursor rows): the fewest chunks that make
-    both row counts multiples of 8, doubled until a step holds at least
-    `min_rows` rows."""
+    Huffman chunk out as `chunk // 128` rows of 128 lanes: the fewest
+    chunks that make the row count a multiple of 8, doubled until a step
+    holds at least `min_rows` rows."""
     if chunk % 128:
         raise ValueError(f"the Pallas Huffman kernels need chunk_size a "
                          f"multiple of 128, got {chunk}")
-    rc, ns = chunk // 128, max(1, chunk // sub)
-    g = math.lcm(8 // math.gcd(rc, 8), 8 // math.gcd(ns, 8))
+    rc = chunk // 128
+    g = 8 // math.gcd(rc, 8)
     while g * rc < min_rows:
         g *= 2
     return g

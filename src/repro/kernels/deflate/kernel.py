@@ -27,6 +27,7 @@ kernel decodes from in parallel — the phase-1 half of Rivera et al.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -54,9 +55,10 @@ def _chunk_prefix(v, seg_row, rc):
 
 
 def _samples(x, sub):
-    """Row-major samples of `x` [R, 128] at every `sub`-th lane, as one
-    lane row [1, spr·R] ordered (lane sample j, row r)."""
-    step = min(sub, 128)
+    """Row-major samples of `x` [R, 128] at every `gcd(sub, 128)`-th lane
+    (each row's lanes that can start a subchunk), as one lane row
+    [1, spr·R] ordered (lane sample j, row r)."""
+    step = math.gcd(sub, 128)
     return jnp.concatenate([x[:, j:j + 1].reshape(1, -1)
                             for j in range(0, 128, step)], axis=1)
 
@@ -116,7 +118,7 @@ def deflate_pallas(cw: jax.Array, bw: jax.Array, chunk_size: int = 512,
     cwp = jnp.pad(jax.lax.bitcast_convert_type(cw.astype(jnp.uint32),
                                                jnp.int32), (0, pad))
     bwp = jnp.pad(bw.astype(jnp.int32), (0, pad))
-    rows, spr = g * rc, max(1, 128 // sub_size)
+    rows, spr = g * rc, 128 // math.gcd(sub_size, 128)
     steps = ncp // g
     spec = pl.BlockSpec((rows, 128), lambda i: (i, 0))
     # per-step stats rows, 8 steps to a block (the (8, 128) rule)
@@ -134,7 +136,9 @@ def deflate_pallas(cw: jax.Array, bw: jax.Array, chunk_size: int = 512,
     words = jax.lax.bitcast_convert_type(words, jnp.uint32)
     stats = stats[:steps]
     bits = stats[:, :rows].reshape(ncp, rc)[:nc, -1]
-    stride = max(1, sub_size // 128)      # sub > 128: every stride-th row
+    # every stride-th sample starts a subchunk (every stride-th row's
+    # first lane, for a sub_size that is a multiple of 128)
+    stride = sub_size // math.gcd(sub_size, 128)
 
     def gaps(a):                          # [steps, spr·R] -> [nc, n_sub]
         a = a.reshape(steps, spr, rows).transpose(0, 2, 1)

@@ -263,6 +263,34 @@ def test_a_decompress_call_records_its_spans_in_order(codec_and_field):
                                               "host_syncs"]
 
 
+def test_a_decompress_call_counts_its_walk_steps_once(codec_and_field,
+                                                      host_sync_sanitizer):
+    from repro.kernels.inflate import kernel as inflate_kernel
+    codec, _, packed = codec_and_field
+    _, arrays = packed
+    nc, n_sub = arrays["gap_bits"].shape
+    chunk = int(arrays["chunk_words"])
+    with dispatch.kernel_policy(overrides={"inflate": "pallas-interpret"}):
+        _decompress(codec, packed)                        # warm
+        t0 = time.perf_counter()
+        with host_sync_sanitizer() as log:
+            _decompress(codec, packed)
+    got, counts = _since(t0)
+    walk = [c for c in counts if c.name == "inflate.walk_steps"]
+    # 16x24x24 in 8x8x8 blocks: 9216 codes, 3 chunks of 4096 and
+    # 32 subchunks each, so one grid step of 4 chunks: 128 walk steps
+    assert (nc, chunk, n_sub) == (3, 4096, 32)
+    assert [c.n for c in walk] == [128]
+    assert inflate_kernel.walk_steps(nc, chunk, chunk // n_sub) == 128
+    # from the static plan once the dispatch is enqueued, no device read
+    dispatch_ = next(s for s in got if s.name == "codec.dispatch")
+    assert dispatch_.t1 <= walk[0].t
+    assert len(log.allowed_hits) == 1 and log.violations == []
+    # the benchmark's fields: 32 chunks a grid step
+    assert inflate_kernel.walk_steps(6450, 4096, 128) == 202 * 128
+    assert inflate_kernel.walk_steps(68593, 4096, 128) == 2144 * 128
+
+
 @pytest.mark.parametrize("op,want", [("compress", 2), ("decompress", 1)])
 def test_host_syncs_equal_the_syncs_the_guard_attributes(
         codec_and_field, host_sync_sanitizer, op, want):

@@ -223,6 +223,24 @@ class TestDeflateKernel:
         np.testing.assert_array_equal(np.asarray(gbk), np.asarray(gbr))
         np.testing.assert_array_equal(np.asarray(gsk), np.asarray(gsr))
 
+    @pytest.mark.parametrize("n,chunk,sub", [(5000, 384, 192),
+                                             (3000, 384, 96),
+                                             (5000, 1024, 256)])
+    def test_gap_arrays_match_ref_at_any_sub(self, n, chunk, sub):
+        """Gap samples at every `sub`-th symbol, also where a subchunk
+        starts mid-row (a sub_size that neither divides 128 nor is a
+        multiple of it)."""
+        rng = np.random.default_rng(n + sub)
+        codes = jnp.asarray(rng.integers(0, 64, n).astype(np.int32))
+        cb = hf.canonical_codebook(hf.codeword_lengths(
+            hf.histogram(codes, 64)))
+        cw, bw = hf.encode(codes, cb)
+        got = deflate_ops.deflate(cw, bw, chunk, sub, impl="pallas")
+        want = deflate_ops.deflate(cw, bw, chunk, sub, impl="jax")
+        assert got[2].shape == (-(-n // chunk), chunk // sub)
+        for a, b in zip(got, want):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
     def test_kernel_stream_decodes(self):
         """Kernel-produced bitstream must inflate back to the input."""
         rng = np.random.default_rng(5)
@@ -241,29 +259,112 @@ class TestDeflateKernel:
         np.testing.assert_array_equal(out.reshape(-1)[:n], codes)
 
 
+def _fibonacci_book(k):
+    """A codebook from Fibonacci frequencies: lengths 1 .. k-1, so with
+    k = 33 its longest codewords are MAXLEN (32) bits."""
+    fib = [1, 1]
+    while len(fib) < k:
+        fib.append(fib[-1] + fib[-2])
+    return hf.canonical_codebook(hf.codeword_lengths(
+        jnp.asarray(fib[::-1], jnp.int32)))
+
+
 class TestInflateKernel:
-    @pytest.mark.parametrize("n,k,chunk,sub", [(2000, 128, 512, 64),
-                                               (700, 1024, 256, 32),
-                                               (4096, 64, 512, 128)])
-    def test_gap_kernel_matches_sequential(self, n, k, chunk, sub):
+    # (symbols, alphabet, chunk, sub, book, trailing chunks of count 0).
+    # The kernel walks up to 8 x 128 subchunk cursors a grid step
+    # (`kernel.tile`): 32 chunks of 4096, 256 of 512.  "flat": uniform
+    # symbols, max_len <= LUT_BITS; "deep": the deepest eight codewords
+    # (25-32 bits) of a Fibonacci book, so a subchunk spans ~115 words
+    # and its walk crosses into the second row of its window.
+    @pytest.mark.parametrize("n,k,chunk,sub,book,pad", [
+        pytest.param(2000, 128, 512, 64, "flat", 0, id="2000-128-512-64"),
+        pytest.param(700, 1024, 256, 32, "flat", 0, id="700-1024-256-32"),
+        pytest.param(4096, 64, 512, 128, "flat", 0, id="4096-64-512-128"),
+        pytest.param(5 * 4096 - 1000, 1024, 4096, 128, "flat", 0,
+                     id="chunk4096-fewer-chunks-than-a-tile"),
+        pytest.param(37 * 4096 - 300, 64, 4096, 128, "flat", 0,
+                     id="chunk4096-two-tiles-the-last-partial"),
+        pytest.param(300 * 512 - 77, 1024, 512, 128, "flat", 0,
+                     id="chunk512-two-tiles-the-last-partial"),
+        pytest.param(1000, 128, 4096, 128, "flat", 6,
+                     id="chunk4096-padded-chunks-count-0"),
+        pytest.param(3 * 4096 + 5, 33, 4096, 128, "deep", 0,
+                     id="chunk4096-deep-book-short-last-chunk"),
+        pytest.param(2500, 33, 512, 128, "deep", 3,
+                     id="chunk512-deep-book-padded-chunks"),
+        pytest.param(3000, 33, 512, 64, "deep", 0,
+                     id="chunk512-sub64-deep-book"),
+        pytest.param(5000, 64, 1024, 256, "flat", 0,
+                     id="sub256-two-windows-a-walk"),
+        pytest.param(3000, 33, 512, 512, "deep", 2,
+                     id="sub512-deep-book-padded-chunks"),
+        pytest.param(5000, 64, 384, 192, "flat", 1,
+                     id="chunk384-sub192-a-last-phase-of-64"),
+        pytest.param(3000, 33, 640, 320, "deep", 0,
+                     id="chunk640-sub320-deep-book-three-phases"),
+    ])
+    def test_gap_kernel_matches_sequential(self, n, k, chunk, sub, book,
+                                           pad):
         """Pallas gap-array inflate == sequential reference, bit-exact;
         the decoded stream equals the original codes."""
         from repro.kernels.inflate import ops as inflate_ops
         rng = np.random.default_rng(n + k)
-        codes = rng.integers(0, k, n).astype(np.int32)
-        cb = hf.canonical_codebook(hf.codeword_lengths(
-            hf.histogram(jnp.asarray(codes), k)))
+        if book == "deep":
+            cb = _fibonacci_book(k)
+            deepest = np.argsort(np.asarray(cb.lengths))[-8:]
+            codes = rng.choice(deepest, n).astype(np.int32)
+        else:
+            codes = rng.integers(0, k, n).astype(np.int32)
+            cb = hf.canonical_codebook(hf.codeword_lengths(
+                hf.histogram(jnp.asarray(codes), k)))
         cw, bw = hf.encode(jnp.asarray(codes), cb)
         words, bits, gap_bits, _ = deflate_ops.deflate(
             cw, bw, chunk, sub, impl="pallas")
+        if pad:
+            # chunks past the stream (a fixed-capacity store's tail):
+            # words and gaps are noise, the count 0 says decode nothing
+            words = jnp.concatenate([words, jnp.asarray(rng.integers(
+                0, 2 ** 32, (pad, chunk), dtype=np.uint32))])
+            bits = jnp.concatenate([bits, jnp.zeros((pad,), bits.dtype)])
+            gap_bits = jnp.concatenate([gap_bits, jnp.asarray(
+                rng.integers(0, 32 * chunk, (pad, chunk // sub)),
+                gap_bits.dtype)])
         nv = jnp.asarray(np.minimum(
             chunk, np.maximum(n - np.arange(words.shape[0]) * chunk, 0)
         ).astype(np.int32))
         ml = hf.bucket_max_len(max(1, int(cb.max_len)))
+        assert int(cb.max_len) == ml == hf.MAXLEN \
+            if book == "deep" else ml <= hf.LUT_BITS
         table = hf.decode_table(cb.lengths, ml)
         seq = np.asarray(hf.inflate(words, bits, nv, cb, ml))
         out = np.asarray(inflate_ops.inflate(
             words, bits, nv, table, ml, gaps=gap_bits,
             impl="pallas-interpret"))
         np.testing.assert_array_equal(out, seq)
+        np.testing.assert_array_equal(out.reshape(-1)[:n], codes)
+        np.testing.assert_array_equal(out.reshape(-1)[n:], 0)
+
+    def test_a_subchunk_too_long_for_a_grid_step_decodes_by_reference(self):
+        """A subchunk of 4096 symbols: one grid step of 128 cursors would
+        hold 8 MiB of words and codes, over the kernel's VMEM budget, so
+        the tile refuses it and the dispatch decodes it with the jax gap
+        reference, bit-exact; half of it fits."""
+        from repro.kernels.inflate import kernel as inflate_kernel
+        from repro.kernels.inflate import ops as inflate_ops
+        assert inflate_kernel.fits(4096, 2048)
+        assert not inflate_kernel.fits(4096, 4096)
+        with pytest.raises(ValueError, match="VMEM"):
+            inflate_kernel.tile(3, 4096, 4096)
+        n, k = 3 * 4096 - 500, 256
+        codes = np.random.default_rng(n).integers(0, k, n).astype(np.int32)
+        cb = hf.canonical_codebook(hf.codeword_lengths(
+            hf.histogram(jnp.asarray(codes), k)))
+        cw, bw = hf.encode(jnp.asarray(codes), cb)
+        words, bits, gap_bits, _ = deflate_ops.deflate(
+            cw, bw, 4096, 4096, impl="pallas")
+        nv = jnp.asarray([4096, 4096, 4096 - 500], jnp.int32)
+        ml = hf.bucket_max_len(int(cb.max_len))
+        out = np.asarray(inflate_ops.inflate(
+            words, bits, nv, hf.decode_table(cb.lengths, ml), ml,
+            gaps=gap_bits, impl="pallas-interpret"))
         np.testing.assert_array_equal(out.reshape(-1)[:n], codes)

@@ -153,22 +153,31 @@ def _encode(spec, on_chip):
 
 def _deflate(spec, on_chip):
     n = _n_codes(HACC)
-    for chunk in (CHUNK, FZ_CHUNK):
+    for chunk, sub in ((CHUNK, SUB), (FZ_CHUNK, SUB), (384, 192)):
         _compiles(lambda cw, bw: deflate_k.deflate_pallas(
-            cw, bw, chunk, SUB, interpret=False),
+            cw, bw, chunk, sub, interpret=False),
             spec((n,), jnp.uint32), spec((n,)))
 
 
 def _inflate(spec, on_chip):
-    nc = -(-_n_codes(HACC) // CHUNK)
-    for ml in (hf.LUT_BITS, hf.MAXLEN):
+    # HACC's 68,593 chunks of 4096 (32 a grid step), and the KV cache's
+    # chunks of 512 (256 a grid step), in both decode regimes; two
+    # subchunks a chunk (a walk of 16 phases); and subchunks of 192 (a
+    # last phase of 64 steps)
+    n = _n_codes(HACC)
+    for chunk, sub, ml in ((CHUNK, SUB, hf.LUT_BITS),
+                           (CHUNK, SUB, hf.MAXLEN),
+                           (FZ_CHUNK, SUB, hf.LUT_BITS),
+                           (CHUNK, CHUNK // 2, hf.LUT_BITS),
+                           (384, 192, hf.LUT_BITS)):
+        nc = -(-n // chunk)
         table = on_chip(jax.eval_shape(
             lambda l, ml=ml: hf.build_decode_table(l, ml),
             jax.ShapeDtypeStruct((NBINS,), jnp.int32)))
         _compiles(lambda w, nv, g, t: inflate_k.inflate_pallas(
-            w, nv, g, t, SUB, interpret=False),
-            spec((nc, CHUNK), jnp.uint32), spec((nc,)),
-            spec((nc, CHUNK // SUB)), table)
+            w, nv, g, t, sub, max_len=ml, interpret=False),
+            spec((nc, chunk), jnp.uint32), spec((nc,)),
+            spec((nc, chunk // sub)), table)
 
 
 def _interp(fn):
